@@ -49,6 +49,8 @@ import inspect
 import sys
 import time
 
+from repro.launch.compile_cache import use_compile_cache
+
 from . import (ablation_m_sweep, atlas, chain_layout, fault_frontier,
                fig5_quadratic, fig8_grid, malenia_het, order_stats_speed,
                sec6_async_needed, sec6_heterogeneous, sec53_gap,
@@ -88,6 +90,7 @@ def main() -> None:
     ap.add_argument("--json", default=None,
                     help="also write rows + timings to this JSON file")
     args = ap.parse_args()
+    use_compile_cache()
 
     print("name,value,derived")
     failures = 0

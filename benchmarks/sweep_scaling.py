@@ -138,6 +138,8 @@ def _spawn(devices: int) -> dict:
     from .subproc import run_json_worker
 
     env = dict(os.environ)
+    # forced host devices by design: the worker must never take the chip
+    env["JAX_PLATFORMS"] = "cpu"
     flag = f"--xla_force_host_platform_device_count={devices}"
     env["XLA_FLAGS"] = f"{env.get('XLA_FLAGS', '')} {flag}".strip()
     env["PYTHONPATH"] = os.pathsep.join(

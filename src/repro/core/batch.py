@@ -42,9 +42,8 @@ Backends (``backend=``):
   local devices. Per-seed results are bitwise identical to
   ``backend="jax"`` (the per-seed key streams are sweep-independent);
   the per-point routing records carry the bucket, compile-vs-execute
-  wall times and program-cache hits. m-sync and Async/Ringmaster
-  shard; Rennala/Malenia fall back to the per-point jax engine inside
-  the sweep (recorded as ``fallback``).
+  wall times and program-cache hits. Every jax engine family shards
+  (``repro.launch.sweep.SHARDED_KINDS``); a failing bucket raises.
 * ``"auto"`` (default) — ``vectorized`` when eligible, else ``serial``.
 * ``"fastest"`` — like ``auto`` but routes each grid point through a
   **per-engine cost model** (:func:`estimate_backend_seconds`): the
@@ -62,15 +61,16 @@ Backends (``backend=``):
   :class:`TraceBatch`. This is what :func:`repro.exp.run_experiment`
   uses.
 
-Engine *execution* failures do not abort a sweep: every grid point runs
-under a degradation ladder (``jax_sharded`` → ``jax`` → ``vectorized`` →
-``serial``, retry-once per rung, skipping rungs that cannot run the
-point) and each downgrade is recorded in the point's
-``TraceBatch.routing`` entry (``downgrades``: engine, exception class,
-reason, fallback target) instead of raising — only the last rung's
-failure propagates. Contract errors on a forced backend (unsupported
-strategy/model, ``tol_grad_sq`` on jax) still raise up front. See
-DESIGN.md §3c.
+Under ``backend="fastest"`` (and ``"auto"``) engine *execution*
+failures do not abort a sweep: every grid point runs under a degradation
+ladder (``jax_sharded`` → ``jax`` → ``vectorized`` → ``serial``,
+retry-once per rung, skipping rungs that cannot run the point) and each
+downgrade is recorded in the point's ``TraceBatch.routing`` entry
+(``downgrades``: engine, exception class, reason, fallback target) —
+only the last rung's failure propagates. A forced backend never
+downgrades: its engine failure raises. Contract errors on a forced
+backend (unsupported strategy/model, ``tol_grad_sq`` on jax) raise up
+front. See DESIGN.md §3c.
 
 Grid semantics: ``grid`` maps parameter names to value sequences and the
 cartesian product is swept. Keys in :data:`SIM_GRID_KEYS` override the
@@ -202,11 +202,8 @@ def _accelerator_present() -> bool:
     already amortized by the sweep."""
     global _ACCEL_PRESENT
     if _ACCEL_PRESENT is None:
-        try:
-            import jax
-            _ACCEL_PRESENT = jax.default_backend() != "cpu"
-        except Exception:          # pragma: no cover - jax always present
-            _ACCEL_PRESENT = False
+        import jax
+        _ACCEL_PRESENT = jax.default_backend() != "cpu"
     return _ACCEL_PRESENT
 
 
@@ -219,11 +216,8 @@ def _device_count() -> int:
     big enough that the jax import is already amortized."""
     global _DEVICE_COUNT
     if _DEVICE_COUNT is None:
-        try:
-            import jax
-            _DEVICE_COUNT = jax.local_device_count()
-        except Exception:           # pragma: no cover - jax always present
-            _DEVICE_COUNT = 1
+        import jax
+        _DEVICE_COUNT = jax.local_device_count()
     return _DEVICE_COUNT
 
 
@@ -601,13 +595,15 @@ def _jax_eligible(strategy: AggregationStrategy, model, problem,
 # the degradation ladder: engine execution failures downgrade, not raise
 # ---------------------------------------------------------------------------
 
-#: Downgrade order for engine *execution* failures (contract errors —
-#: unsupported strategy/model combos on a forced backend — still raise
-#: at validation time, before any engine runs). A failing engine is
-#: retried once, then the point falls to the next rung that can run it;
-#: every hop is recorded in the point's routing entry
-#: (``routing[g]["downgrades"]``). Only when the last rung fails does
-#: the exception propagate.
+#: Downgrade order for engine *execution* failures under
+#: ``backend="fastest"`` and ``"auto"`` (contract errors — unsupported
+#: strategy/model combos — raise at validation time, before any engine
+#: runs). A failing engine is retried once, then the point falls to the
+#: next rung that can run it; every hop is recorded in the point's
+#: routing entry (``routing[g]["downgrades"]``). Only when the last rung
+#: fails does the exception propagate. A FORCED backend never
+#: downgrades: its engine failure raises, so a run that asked for the
+#: device engine never quietly lands on a host engine.
 ENGINE_LADDER = ("jax_sharded", "jax", "vectorized", "serial")
 
 
@@ -709,6 +705,7 @@ def simulate_batch(strategy: StrategySpec,
     if rng_scheme not in ("counter", "stream"):
         raise ValueError(f"unknown rng_scheme {rng_scheme!r}; "
                          "use 'counter' or 'stream'")
+    forced = backend not in ("auto", "fastest")
     name, factory, base_kw = _as_spec(strategy)
     points = _grid_points(grid)
 
@@ -793,6 +790,8 @@ def simulate_batch(strategy: StrategySpec,
                  run_engine, strat, K_pt, tol_pt))
             row = None             # filled by the fused sweep below
             actual = chosen
+        elif forced:
+            actual, row = chosen, run_engine(chosen)
         else:
             downs = _ladder_below(chosen, strat, model, problem, K_pt,
                                   tol_pt)
@@ -807,18 +806,26 @@ def simulate_batch(strategy: StrategySpec,
 
     if sharded_points:
         # ONE fused, shape-bucketed, shard_mapped launch for every grid
-        # point routed to the sharded sweep backend (retry-once, then
-        # each deferred point falls down the ladder from "jax")
+        # point routed to the sharded sweep backend. Forced: a failure
+        # raises. Routed by fastest: retry once, then each deferred
+        # point falls down the ladder from "jax"
         from ..launch.sweep import run_sharded_sweep
+
+        def run_sweep():
+            return run_sharded_sweep(
+                [sp for _, sp, *_ in sharded_points], model, problem,
+                seed_list, use_pallas=use_pallas, x64=x64)
+
         results = fused_exc = None
-        for _attempt in range(2):
-            try:
-                results = run_sharded_sweep(
-                    [sp for _, sp, *_ in sharded_points], model, problem,
-                    seed_list, use_pallas=use_pallas, x64=x64)
-                break
-            except Exception as exc:
-                fused_exc = exc
+        if forced:
+            results = run_sweep()
+        else:
+            for _attempt in range(2):
+                try:
+                    results = run_sweep()
+                    break
+                except Exception as exc:
+                    fused_exc = exc
         if results is not None:
             for g, *_ in sharded_points:
                 row, shard_rec = results[g]

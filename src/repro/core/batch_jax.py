@@ -156,7 +156,9 @@ def quadratic_worst_case_jax(d: int = 1000, p: float = 0.1,
         return y
 
     def f(x):
-        return 0.5 * x @ matvec(x) - b @ x - f_star
+        # elementwise products + sums, not dots: a float32 dot may run
+        # as a reduced-precision matmul pass on TPU
+        return jnp.sum(x * (0.5 * matvec(x) - b)) - f_star
 
     def grad(x):
         return matvec(x) - b
@@ -541,7 +543,6 @@ def sharded_msync_run(model, problem, n, S, K, seeds, m_list, gamma_list,
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     math = problem is not None
@@ -586,14 +587,14 @@ def sharded_msync_run(model, problem, n, S, K, seeds, m_list, gamma_list,
         return comp, x, T, val, gn
 
     P = PartitionSpec
-    # check_rep=False: no collectives anywhere in the program, and jax
-    # 0.4.x has no replication rule for the selection's while_loop
-    wrapped = shard_map(
+    # check_vma=False: the program has no collectives, so there is no
+    # varying-across-devices type to check
+    wrapped = jax.shard_map(
         unit_prog, mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"), P("data")),
         out_specs=(P("data"), P("data"), P(None, "data"), P(None, "data"),
                    P(None, "data")),
-        check_rep=False)
+        check_vma=False)
 
     key = ("msync", math, m_static, n, S, K,
            bool(jax.config.jax_enable_x64), _mesh_cache_key(mesh),
@@ -697,14 +698,13 @@ def _rennala_run(model, problem, B, n, S, K, gamma, use_pallas, seeds,
     if mesh is None:
         return jax.block_until_ready(jax.jit(unit_prog)(keys0, x_init))
 
-    from jax.experimental.shard_map import shard_map
     P = PartitionSpec
-    wrapped = shard_map(
+    wrapped = jax.shard_map(
         unit_prog, mesh=mesh,
         in_specs=(P("data"), P("data")),
         out_specs=(P("data"), P("data"), P(None, "data"), P(None, "data"),
                    P(None, "data")),
-        check_rep=False)
+        check_vma=False)
     key = ("rennala", math, B, n, S, K, float(gamma), bool(use_pallas),
            bool(jax.config.jax_enable_x64), _mesh_cache_key(mesh),
            _ById(model), _ById(problem))
@@ -943,14 +943,13 @@ def _malenia_run(model, problem, S_target, n, S, K, gamma, seeds,
         if mesh is None:
             return jax.block_until_ready(jax.jit(unit_prog)(keys0, x_init))
 
-        from jax.experimental.shard_map import shard_map
         P = PartitionSpec
-        wrapped = shard_map(
+        wrapped = jax.shard_map(
             unit_prog, mesh=mesh,
             in_specs=(P("data"), P("data")),
             out_specs=(P("data"), P("data"), P("data"), P(None, "data"),
                        P(None, "data"), P(None, "data"), P("data")),
-            check_rep=False)
+            check_vma=False)
         key = ("malenia", math, float(S_target), L, n, S, K, float(gamma),
                bool(jax.config.jax_enable_x64), _mesh_cache_key(mesh),
                _ById(model), _ById(problem))
@@ -1172,13 +1171,12 @@ def _ringleader_run(model, problem, n, S, K, gamma, seeds, chain_len=None,
 
         if mesh is None:
             return _prog_cache_put(_SWEEP_PROGS, key, jax.jit(unit_prog))
-        from jax.experimental.shard_map import shard_map
         P = PartitionSpec
-        wrapped = shard_map(
+        wrapped = jax.shard_map(
             unit_prog, mesh=mesh,
             in_specs=(P("data"),) * 7,
             out_specs=(P("data"),) * 7 + (P(None, "data"),) * 3,
-            check_rep=False)
+            check_vma=False)
         return _prog_cache_put(_SWEEP_PROGS, key, jax.jit(wrapped))
 
     ch_flat = draw_to(budgets)
@@ -1327,12 +1325,10 @@ def _shard_wrap(fn, mesh, in_specs, out_specs):
 
     if mesh is None:
         return jax.jit(fn)
-    from jax.experimental.shard_map import shard_map
-
-    # check_rep=False: these programs have no collectives, and jax 0.4.x
-    # lacks replication rules for some of their primitives (while_loop)
-    return jax.jit(shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False))
+    # check_vma=False: these programs have no collectives, so there is
+    # no varying-across-devices type to check
+    return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                                 out_specs=out_specs, check_vma=False))
 
 
 def _mesh_rows(S: int, mesh) -> int:
@@ -1984,7 +1980,7 @@ def simulate_batch_jax(strategy: AggregationStrategy,
     under x64) since certified arrivals are layout-independent.
 
     ``x64=True`` runs the whole program in float64 (via
-    ``jax.experimental.enable_x64``): slower, but gives per-run tie
+    ``jax.enable_x64``): slower, but gives per-run tie
     parity with the float64 NumPy event heap on adversarially tie-heavy
     instances (flat-power partial participation) where float32
     tie-breaking diverges by whole events.
@@ -1999,8 +1995,7 @@ def simulate_batch_jax(strategy: AggregationStrategy,
     import jax.numpy as jnp
 
     if x64 and not jax.config.jax_enable_x64:
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             return simulate_batch_jax(
                 strategy, model, K, problem=problem, gamma=gamma,
                 seeds=seeds, record_every=record_every,

@@ -802,7 +802,7 @@ class UniversalModel:
     def _jax_arrays(self):
         """(grid, cum, powers) as jnp arrays, cached per x64 mode (the
         cache key matters: tests run the 1e-9 parity check under
-        ``jax.experimental.enable_x64`` while the engines default to
+        ``jax.enable_x64`` while the engines default to
         float32)."""
         import jax
         import jax.numpy as jnp

@@ -1,4 +1,4 @@
-"""Flash attention Pallas kernel (TPU target, interpret-mode validated).
+"""Flash attention Pallas kernel (TPU target; interpreted on CPU).
 
 Online-softmax tiled attention: for each (batch, q-head, q-block) program
 instance, stream KV blocks through VMEM, maintaining the running max ``m``,
@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import pallas_call
+
 __all__ = ["flash_attention_pallas"]
 
 NEG_INF = -1e30
@@ -54,10 +56,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
 
     def body(kj, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(kj * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(kj * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
         s = q @ k.T                                   # (bq, bk)
         q_pos = qi * block_q + jax.lax.iota(jnp.int32, block_q)
         k_pos = kj * block_k + jax.lax.iota(jnp.int32, block_k)
@@ -92,11 +92,10 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            window: Optional[int] = None,
-                           block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           block_q: int = 128, block_k: int = 128):
     """q: (B, Sq, H, dh); k, v: (B, Sk, KV, dh) with H % KV == 0.
-    Returns (B, Sq, H, dh). ``interpret=True`` runs the kernel body in
-    Python on CPU (this container); on TPU pass ``interpret=False``.
+    Returns (B, Sq, H, dh). Interpreted on CPU, compiled on TPU
+    (:func:`repro.kernels.platform.pallas_call`).
     """
     B, Sq, H, dh = q.shape
     _, Sk, KV, _ = k.shape
@@ -126,7 +125,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         _attn_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, seq_k=Sk)
 
-    out = pl.pallas_call(
+    out = pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -142,7 +141,6 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
         out_specs=pl.BlockSpec((None, None, block_q, dh),
                                lambda b, h, i: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Sqp, dh), q.dtype),
-        interpret=interpret,
     )(qt, kt, vt)
     out = out.transpose(0, 2, 1, 3)
     if pad_q:

@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import pallas_call
+
 __all__ = ["moe_gmm_pallas"]
 
 
@@ -41,8 +43,9 @@ def _gmm_kernel(x_ref, w_ref, o_ref, acc, *, n_k: int):
 
 
 def moe_gmm_pallas(x, w, *, block_m: int = 128, block_n: int = 128,
-                   block_k: int = 128, interpret: bool = True):
-    """x: (E, C, din); w: (E, din, dout) -> (E, C, dout)."""
+                   block_k: int = 128):
+    """x: (E, C, din); w: (E, din, dout) -> (E, C, dout). Interpreted on
+    CPU, compiled on TPU (:func:`repro.kernels.platform.pallas_call`)."""
     E, C, din = x.shape
     _, _, dout = w.shape
     block_m = min(block_m, C)
@@ -58,7 +61,7 @@ def moe_gmm_pallas(x, w, *, block_m: int = 128, block_n: int = 128,
     Cp, dinp, doutp = x.shape[1], x.shape[2], w.shape[2]
     n_k = dinp // block_k
 
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_gmm_kernel, n_k=n_k),
         grid=(E, Cp // block_m, doutp // block_n, n_k),
         in_specs=[
@@ -71,6 +74,5 @@ def moe_gmm_pallas(x, w, *, block_m: int = 128, block_n: int = 128,
                                lambda e, i, j, kk: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, Cp, doutp), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=interpret,
     )(x, w)
     return out[:, :C, :dout]

@@ -1,53 +1,36 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True because this container is CPU-only; the
-``REPRO_PALLAS_INTERPRET`` environment variable overrides it
-(``REPRO_PALLAS_INTERPRET=0`` compiles the kernels — the real-TPU CI
-lane and the launcher set this; anything else, or unset, keeps the
-CPU-safe interpreter). The model code reaches these via
-``cfg/impl == "pallas"`` (models/attention.py, models/ssm.py).
+Each kernel is interpreted on CPU and compiled on TPU, chosen by the
+platform the program is lowered for (:mod:`repro.kernels.platform`). The
+model code reaches these via ``impl == "pallas"`` (models/attention.py,
+models/ssm.py).
 """
 
 from __future__ import annotations
 
 import functools
-import os
 from typing import Optional
 
 import jax
 
 from .flash_attention import flash_attention_pallas
 from .moe_gmm import moe_gmm_pallas
-from .order_stats import mth_smallest as _mth_smallest_dispatch
 from .rwkv_scan import rwkv_scan_pallas
 
-__all__ = ["flash_attention", "rwkv_scan", "moe_gmm", "mth_smallest"]
-
-# CPU container default: interpret. REPRO_PALLAS_INTERPRET=0 => compiled
-# Pallas lowering (real TPU runs / the opt-in CI lane).
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
-
-@functools.partial(jax.jit, static_argnames=("m",))
-def mth_smallest(x, *, m: int):
-    # CPU (INTERPRET=True): fused iterative/top_k dispatch; on TPU the
-    # VMEM-resident Pallas partial-sort kernel
-    return _mth_smallest_dispatch(x, m, use_pallas=not INTERPRET,
-                                  interpret=INTERPRET)
+__all__ = ["flash_attention", "rwkv_scan", "moe_gmm"]
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None):
-    return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  interpret=INTERPRET)
+    return flash_attention_pallas(q, k, v, causal=causal, window=window)
 
 
 @jax.jit
 def rwkv_scan(r, k, v, w, u):
-    return rwkv_scan_pallas(r, k, v, w, u, interpret=INTERPRET)
+    return rwkv_scan_pallas(r, k, v, w, u)
 
 
 @jax.jit
 def moe_gmm(x, w):
-    return moe_gmm_pallas(x, w, interpret=INTERPRET)
+    return moe_gmm_pallas(x, w)

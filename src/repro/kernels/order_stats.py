@@ -12,9 +12,8 @@ For ``m = n`` the statistic degenerates to ``max``; for large ``m < n``
 we fall back to ``lax.top_k`` (fine on TPU, the intended accelerator).
 
 ``mth_smallest_pallas`` is the same selection as a Pallas TPU kernel
-(whole block in VMEM, ``fori_loop`` extraction) — validated in interpret
-mode on CPU, worth using compiled on TPU where VMEM-resident iteration
-beats a full sort for small ``m``.
+(whole block in VMEM, ``fori_loop`` extraction): interpreted on CPU,
+compiled on TPU (:mod:`repro.kernels.platform`).
 
 For large ``m`` (``m > _MAX_ITERATIVE_M``, the Rennala/Malenia
 ``batch >> 64`` pools) the extraction loop's ``O(m · n)`` cost loses, but
@@ -38,7 +37,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
+
+from .platform import pallas_call
 
 __all__ = ["mth_smallest", "mth_smallest_iterative", "mth_smallest_counting",
            "mth_smallest_rowwise", "mth_smallest_pallas", "smallest_k"]
@@ -51,38 +51,43 @@ _COUNT_BISECT_ITERS = 26
 _COUNT_SNAP_ITERS = 8
 
 
-def _extract_mth(x: jnp.ndarray, m: int) -> jnp.ndarray:
-    """The shared tie-class-extraction loop (plain jax AND Pallas body).
+def _extract_mth_keep(x: jnp.ndarray, m: int) -> jnp.ndarray:
+    """The shared tie-class-extraction loop (plain jax AND Pallas body),
+    returning the statistic with a kept trailing axis: ``(..., 1)``.
 
     Each of the ``m`` iterations removes the entire tie class of the
     running minimum, so duplicated values are counted with multiplicity
-    and the loop can stop early (per row) once ``m`` elements are
-    covered. Elementwise ops only — fuses into enclosing scans and is
-    legal inside a Pallas kernel.
+    and the loop stops taking values (per row) once ``m`` elements are
+    covered. Elementwise ops only — fuses into enclosing scans. The loop
+    carry is all ``(..., 1)`` int32/float arrays (no 1-D or bool
+    vectors), the form the TPU kernel compiler can carry through an
+    ``scf.for``.
     """
-    batch = x.shape[:-1]
+    keep = x.shape[:-1] + (1,)
 
     def body(_, carry):
         rest, killed, val, done = carry
-        mn = rest.min(axis=-1)
+        mn = rest.min(axis=-1, keepdims=True)
+        tie = rest == mn
         # explicit int32: under x64 a bool sum defaults to int64, which
         # would promote the carried counter and break the fori_loop carry
-        c = (rest == mn[..., None]).sum(axis=-1, dtype=jnp.int32)
-        hit = (~done) & (killed + c >= m)
+        killed = killed + tie.astype(jnp.int32).sum(axis=-1, keepdims=True,
+                                                    dtype=jnp.int32)
+        hit = (done == 0) & (killed >= m)
         val = jnp.where(hit, mn, val)
-        done = done | hit
-        rest = jnp.where(rest == mn[..., None], jnp.inf, rest)
-        return rest, killed + c, val, done
+        done = jnp.where(hit, 1, done)
+        rest = jnp.where(tie, jnp.inf, rest)
+        return rest, killed, val, done
 
-    init = (x, jnp.zeros(batch, jnp.int32), jnp.zeros(batch, x.dtype),
-            jnp.zeros(batch, bool))
+    init = (x, jnp.zeros(keep, jnp.int32), jnp.zeros(keep, x.dtype),
+            jnp.zeros(keep, jnp.int32))
     _, _, val, _ = lax.fori_loop(0, m, body, init)
     return val
 
 
 def mth_smallest_iterative(x: jnp.ndarray, m: int) -> jnp.ndarray:
     """m-th smallest along the last axis via tie-class extraction."""
-    return _extract_mth(x, m)
+    return _extract_mth_keep(x, m)[..., 0]
 
 
 def _counting_select(x: jnp.ndarray, m: int):
@@ -232,37 +237,34 @@ def smallest_k(x, k: int, *, prefer_host: bool = None):
 
 
 def _mth_smallest_kernel(m: int, x_ref, o_ref):
-    o_ref[...] = _extract_mth(x_ref[...], m)[..., None]
+    o_ref[...] = _extract_mth_keep(x_ref[...], m)
 
 
-def mth_smallest_pallas(x: jnp.ndarray, m: int, *,
-                        interpret: bool = True) -> jnp.ndarray:
+def mth_smallest_pallas(x: jnp.ndarray, m: int) -> jnp.ndarray:
     """Pallas top-m partial-sort kernel: ``(S, n) -> (S,)``.
 
     One VMEM-resident block; the selection loop never leaves on-chip
-    memory. ``interpret=True`` runs the kernel body in Python on CPU
-    (this container); pass ``interpret=False`` on TPU.
+    memory. Interpreted on CPU, compiled on TPU
+    (:func:`repro.kernels.platform.pallas_call`).
     """
     if x.ndim != 2:
         raise ValueError(f"expected (rows, n), got {x.shape}")
-    out = pl.pallas_call(
+    out = pallas_call(
         functools.partial(_mth_smallest_kernel, m),
         out_shape=jax.ShapeDtypeStruct((x.shape[0], 1), x.dtype),
-        interpret=interpret,
     )(x)
     return out[:, 0]
 
 
-def mth_smallest(x: jnp.ndarray, m: int, *, use_pallas: bool = False,
-                 interpret: bool = True) -> jnp.ndarray:
+def mth_smallest(x: jnp.ndarray, m: int, *,
+                 use_pallas: bool = False) -> jnp.ndarray:
     """m-th smallest along the last axis, backend chosen by shape/flags."""
     n = x.shape[-1]
     if not 1 <= m <= n:
         raise ValueError(f"m={m} out of range [1, {n}]")
     if use_pallas:
         shape = x.shape
-        return mth_smallest_pallas(x.reshape(-1, n), m,
-                                   interpret=interpret).reshape(shape[:-1])
+        return mth_smallest_pallas(x.reshape(-1, n), m).reshape(shape[:-1])
     if m == n:
         return x.max(axis=-1)
     if m <= _MAX_ITERATIVE_M:

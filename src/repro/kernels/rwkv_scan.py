@@ -26,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import pallas_call
+
 __all__ = ["rwkv_scan_pallas"]
 
 
@@ -38,11 +40,11 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_out_ref,
     s_scratch[...] = jnp.zeros((K, V), jnp.float32)
 
     def chunk_body(c, _):
-        sl = pl.dslice(c * chunk, chunk)
-        r = pl.load(r_ref, (sl, slice(None))).astype(jnp.float32)
-        k = pl.load(k_ref, (sl, slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (sl, slice(None))).astype(jnp.float32)
-        w = pl.load(w_ref, (sl, slice(None))).astype(jnp.float32)
+        sl = pl.ds(c * chunk, chunk)
+        r = r_ref[sl, :].astype(jnp.float32)
+        k = k_ref[sl, :].astype(jnp.float32)
+        v = v_ref[sl, :].astype(jnp.float32)
+        w = w_ref[sl, :].astype(jnp.float32)
         u = u_ref[...].astype(jnp.float32)            # (K,)
         s = s_scratch[...]
 
@@ -63,17 +65,18 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_out_ref,
         # state writes use (Ptot / P_j) * k_j — kP already holds k_j / P_j
         s_new = s * Ptot[:, None] + (Ptot[None, :] * kP).T @ v
         s_scratch[...] = s_new
-        pl.store(y_ref, (sl, slice(None)), y.astype(y_ref.dtype))
+        y_ref[sl, :] = y.astype(y_ref.dtype)
         return 0
 
     jax.lax.fori_loop(0, n_chunks, chunk_body, 0)
     s_out_ref[...] = s_scratch[...].astype(s_out_ref.dtype)
 
 
-def rwkv_scan_pallas(r, k, v, w, u, *, chunk: int = 64,
-                     interpret: bool = True):
+def rwkv_scan_pallas(r, k, v, w, u, *, chunk: int = 64):
     """r,k,w: (B, T, H, K); v: (B, T, H, V); u: (H, K).
-    Returns (y (B, T, H, V), state (B, H, K, V)). T padded to chunk."""
+    Returns (y (B, T, H, V), state (B, H, K, V)). T padded to chunk.
+    Interpreted on CPU, compiled on TPU
+    (:func:`repro.kernels.platform.pallas_call`)."""
     B, T, H, K = r.shape
     V = v.shape[-1]
     pad = (-T) % chunk
@@ -91,7 +94,7 @@ def rwkv_scan_pallas(r, k, v, w, u, *, chunk: int = 64,
     wt = w.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(_rwkv_kernel, chunk=chunk, seq=Tp)
-    y, s = pl.pallas_call(
+    y, s = pallas_call(
         kernel,
         grid=(B, H),
         in_specs=[
@@ -110,7 +113,6 @@ def rwkv_scan_pallas(r, k, v, w, u, *, chunk: int = 64,
             jax.ShapeDtypeStruct((B, H, K, V), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((K, V), jnp.float32)],
-        interpret=interpret,
     )(rt, kt, vt, wt, u)
     y = y.transpose(0, 2, 1, 3)[:, :T]
     return y, s

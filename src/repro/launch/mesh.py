@@ -9,6 +9,7 @@ initializes, and smoke tests must see 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_mesh_auto", "make_production_mesh", "POD_SHAPE",
            "MULTIPOD_SHAPE"]
@@ -18,18 +19,9 @@ MULTIPOD_SHAPE = (2, 16, 16)         # 2 pods = 512 chips
 
 
 def make_mesh_auto(shape, axes):
-    """``jax.make_mesh`` with Auto axis types, portable across jax versions.
-
-    We shard via in_shardings + constraints (GSPMD), not the
-    explicit-sharding API. ``AxisType`` only exists on jax >= 0.5; older
-    jax is Auto-only, so plain ``make_mesh`` is equivalent there.
-    """
-    try:
-        from jax.sharding import AxisType
-    except ImportError:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(AxisType.Auto,) * len(axes))
+    """``jax.make_mesh`` with Auto axis types: we shard via
+    in_shardings + constraints (GSPMD), not the explicit-sharding API."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -35,9 +35,10 @@ Async/Ringmaster arrival scan (chain build + scan sharded over units;
 pool merge and compaction host-side as in the unsharded engine), and
 the whole round-scan family — Rennala and Malenia renewal round scans
 and the Ringleader chunked ragged-chain round scan — each
-``shard_map``ped over the unit rows with AOT program caching. No
-engine family routes to per-point ``fallback`` anymore; the branch
-remains only as the safety net for future non-shardable kinds.
+``shard_map``ped over the unit rows with AOT program caching. A bucket
+that fails raises; the caller decides whether to downgrade
+(:func:`repro.core.simulate_batch` does so only under
+``backend="fastest"``).
 
 Multi-host: the mesh covers the local process's devices;
 :func:`is_coordinator` (``jax.process_index() == 0``) gates artifact
@@ -61,8 +62,8 @@ __all__ = ["SweepPoint", "sweep_device_count", "is_coordinator",
            "sweep_mesh", "sweep_shard_ctx", "shardable_kind",
            "run_sharded_sweep"]
 
-#: jax engine families with a sharded program (everything else falls
-#: back to the per-point unsharded jax engine inside the sweep)
+#: jax engine families with a sharded program (every family
+#: :func:`repro.core.batch_jax._classify` knows)
 SHARDED_KINDS = ("msync", "async", "ringmaster", "optimal_asgd",
                  "rennala", "malenia", "ringleader")
 
@@ -111,8 +112,7 @@ def sweep_shard_ctx(devices: Optional[int] = None):
 
 
 def shardable_kind(strategy, model, problem) -> Optional[str]:
-    """The engine family a sharded program exists for, or None (the
-    point still runs inside the sweep, via per-point fallback)."""
+    """The engine family a sharded program exists for, or None."""
     from ..core.batch_jax import _classify
 
     kind = _classify(strategy)
@@ -144,7 +144,7 @@ def _bucket_key(kind: Optional[str], point: SweepPoint, math: bool):
     if kind == "ringleader":
         return ("ringleader", int(point.K),
                 float(point.gamma) if math else 0.0)
-    return ("fallback", point.index)
+    raise ValueError(f"no sharded program for engine kind {kind!r}")
 
 
 def run_sharded_sweep(points: Sequence[SweepPoint], model, problem,
@@ -161,8 +161,7 @@ def run_sharded_sweep(points: Sequence[SweepPoint], model, problem,
     import jax
 
     if x64 and not jax.config.jax_enable_x64:
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             return run_sharded_sweep(points, model, problem, seeds,
                                      use_pallas=use_pallas, x64=False,
                                      mesh=mesh)
@@ -190,16 +189,6 @@ def run_sharded_sweep(points: Sequence[SweepPoint], model, problem,
         base_rec = {"bucket": "/".join(str(b) for b in bkey),
                     "devices": D, "points_in_bucket": len(bpoints),
                     "units": len(bpoints) * S}
-        if bkey[0] == "fallback":
-            # no sharded program for this family yet: plain jax engine
-            p = bpoints[0]
-            traces = bj.simulate_batch_jax(
-                p.strategy, model, p.K, problem=problem, gamma=p.gamma,
-                seeds=seeds, record_every=p.record_every,
-                use_pallas=use_pallas)
-            out[p.index] = (traces, {**base_rec, "fallback": True})
-            return out
-
         # flatten point-major so each point's seeds are one column slice
         unit_seeds = [int(s) for p in bpoints for s in seeds]
         U0 = len(unit_seeds)
@@ -278,30 +267,7 @@ def run_sharded_sweep(points: Sequence[SweepPoint], model, problem,
                                          **meta})
         return out
 
-    # Per-bucket degradation (DESIGN §3c): a failing sharded bucket is
-    # retried once, then its points run the plain per-point jax engine
-    # with the downgrade recorded in the per-point shard meta. Only if
-    # the per-point engine also fails does the exception propagate (the
-    # simulate_batch fused ladder takes over from there).
     out: Dict[int, Tuple[List[Any], Dict[str, Any]]] = {}
     for bkey, bpoints in buckets.items():
-        try:
-            out.update(_run_bucket(bkey, bpoints))
-        except Exception:
-            try:
-                out.update(_run_bucket(bkey, bpoints))
-            except Exception as exc:
-                down = {"from": "jax_sharded:bucket", "to": "jax",
-                        "error": type(exc).__name__,
-                        "reason": str(exc)[:300], "retried": True}
-                for p in bpoints:
-                    traces = bj.simulate_batch_jax(
-                        p.strategy, model, p.K, problem=problem,
-                        gamma=p.gamma, seeds=seeds,
-                        record_every=p.record_every,
-                        use_pallas=use_pallas)
-                    out[p.index] = (traces, {
-                        "bucket": "/".join(str(b) for b in bkey),
-                        "devices": D, "fallback": True,
-                        "downgrades": [down]})
+        out.update(_run_bucket(bkey, bpoints))
     return out

@@ -6,16 +6,16 @@ Examples (CPU, reduced scale):
   PYTHONPATH=src python -m repro.launch.train --arch llama3.2-3b --reduced \
       --steps 50 --policy auto_m
 
-On a real TPU mesh the same entry point takes ``--mesh single|multi`` and
-builds the production mesh + ShardCtx (this container is CPU-only, so the
-mesh path is exercised by the dry-run instead).
+The command line runs on one device. :func:`build_run` takes a
+data-parallel ``ShardCtx`` for a mesh (``chip_smoke.py --four-chips``
+drives it on four chips).
 """
 
 from __future__ import annotations
 
 import argparse
-import dataclasses
 import json
+from typing import Optional
 
 import numpy as np
 
@@ -25,7 +25,9 @@ from ..core import (FixedTimes, SyncMode, SyncPolicy, exponential_times,
 from ..data import SyntheticLM
 from ..models import build_model
 from ..optim import adamw, cosine_schedule, sgd
+from ..sharding.specs import ShardCtx
 from ..train import Trainer, save_checkpoint
+from .compile_cache import use_compile_cache
 
 
 def build_time_model(name: str, n: int):
@@ -42,6 +44,40 @@ def build_time_model(name: str, n: int):
     if name == "truncnorm_sqrt":
         return truncated_normal_times(np.sqrt(np.arange(1, n + 1)), 0.5)
     raise ValueError(name)
+
+
+def build_run(arch: str = "nanogpt-paper", *, steps: int = 100,
+              batch: int = 16, seq: int = 128, lr: float = 3e-3,
+              optimizer: str = "adamw", policy: str = "full",
+              m: Optional[int] = None, deadline: Optional[float] = None,
+              workers: int = 8, time_model: str = "sqrt",
+              remat: bool = False, seed: int = 0, reduced: bool = False,
+              d_model: int = 256, ctx: Optional[ShardCtx] = None):
+    """The launcher's run, built but not started:
+    ``(cfg, trainer, data)``. :func:`main` maps its flags onto these
+    arguments; ``ctx`` (not a flag) puts the trainer on a
+    data-parallel mesh."""
+    cfg = get_config(arch)
+    if reduced or cfg.param_count() > 1e9:
+        cfg = reduce_cfg(cfg, d_model=d_model, layers_per_stage=2,
+                         vocab=min(cfg.vocab_size, 2048))
+    model = build_model(cfg)
+
+    sched = cosine_schedule(lr, warmup=max(steps // 20, 1), total=steps)
+    opt = {"adamw": lambda: adamw(lr=sched),
+           "sgd": lambda: sgd(lr=sched),
+           "sgdm": lambda: sgd(lr=sched, momentum=0.9)}[optimizer]()
+
+    sync = SyncPolicy(mode=SyncMode(policy), m=m, deadline=deadline)
+    tm = build_time_model(time_model, workers)
+    if sync.mode != SyncMode.FULL and tm is None:
+        raise SystemExit("--policy requires a --time-model")
+
+    trainer = Trainer(model, opt, n_workers=workers, sync_policy=sync,
+                      time_model=tm, ctx=ctx, remat=remat, seed=seed)
+    data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=seq,
+                       batch_size=batch, seed=seed)
+    return cfg, trainer, data
 
 
 def main():
@@ -69,35 +105,18 @@ def main():
     ap.add_argument("--ckpt", default=None)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
-    cfg = get_config(args.arch)
-    if args.reduced or cfg.param_count() > 1e9:
-        cfg = reduce_cfg(cfg, d_model=args.d_model, layers_per_stage=2,
-                         vocab=min(cfg.vocab_size, 2048))
-    model = build_model(cfg)
-
-    sched = cosine_schedule(args.lr, warmup=max(args.steps // 20, 1),
-                            total=args.steps)
-    opt = {"adamw": lambda: adamw(lr=sched),
-           "sgd": lambda: sgd(lr=sched),
-           "sgdm": lambda: sgd(lr=sched, momentum=0.9)}[args.optimizer]()
-
-    policy = SyncPolicy(
-        mode=SyncMode(args.policy),
-        m=args.m, deadline=args.deadline)
-    tm = build_time_model(args.time_model, args.workers)
-    if policy.mode != SyncMode.FULL and tm is None:
-        raise SystemExit("--policy requires a --time-model")
-
-    trainer = Trainer(model, opt, n_workers=args.workers,
-                      sync_policy=policy, time_model=tm,
-                      remat=args.remat, seed=args.seed)
+    cfg, trainer, data = build_run(
+        args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
+        lr=args.lr, optimizer=args.optimizer, policy=args.policy,
+        m=args.m, deadline=args.deadline, workers=args.workers,
+        time_model=args.time_model, remat=args.remat, seed=args.seed,
+        reduced=args.reduced, d_model=args.d_model)
     state = trainer.init_state()
-    data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=args.seq,
-                       batch_size=args.batch, seed=args.seed)
 
     print(f"arch={cfg.name} params={cfg.param_count() / 1e6:.1f}M "
-          f"policy={policy.mode.value} workers={args.workers} "
+          f"policy={args.policy} workers={args.workers} "
           f"time_model={args.time_model}")
     hist = trainer.run(state, iter(data), num_steps=args.steps,
                        log_every=args.log_every)

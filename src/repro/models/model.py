@@ -53,8 +53,13 @@ class Model:
     # ---------------- embedding helpers ----------------
     def _embed(self, params, tokens, ctx, offset: int = 0):
         cfg = self.cfg
-        x = params["embed"]["embed"][tokens]            # (B, S, D)
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        table = params["embed"]["embed"]
+        # gather from a float32 view: the transpose, a scatter-add of
+        # every token's gradient into its row, then accumulates in
+        # float32 — in bf16 the updates to frequent tokens' rows are
+        # rounded away as the row's sum grows
+        x = table.astype(jnp.float32)[tokens].astype(table.dtype)
+        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)  # (B, S, D)
         if cfg.pos_embed == "learned":
             S = tokens.shape[1]
             pos = params["embed"]["pos_embed"][offset:offset + S]

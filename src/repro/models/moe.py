@@ -23,7 +23,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .layers import dense_init
 
@@ -196,13 +195,13 @@ def moe_apply(p: dict, x: jnp.ndarray, ctx, cfg,
         up_spec = P(model_axis, None, w_dp)
         out_spec = P(dp_spec, None, model_axis) if scatter \
             else P(dp_spec, None, None)
-        out, aux = shard_map(
+        out, aux = jax.shard_map(
             mapped, mesh=ctx.mesh,
             in_specs=(P(dp_spec, None, None), P(None, None),
                       up_spec if "expert_gate" in p else P(),
                       up_spec, P(model_axis, w_dp, None)),
             out_specs=(out_spec, P()),
-            check_rep=False,
+            check_vma=False,
         )(x, p["router"], p.get("expert_gate", jnp.zeros((), x.dtype)),
           p["expert_up"], p["expert_down"])
 

@@ -13,8 +13,10 @@ Every step:
      times — loss curves are reported against *time*, like the paper's
      figures.
 
-Works on CPU (smoke scale) and, unchanged, on a real mesh: the jitted step
-is shape-identical; only `ctx` changes.
+Works on CPU (smoke scale) and, unchanged, on a data-parallel mesh: the
+jitted step is shape-identical; only `ctx` changes. With a mesh,
+parameters and optimizer state are replicated and each batch (and its
+participation weights) is split over the data axes.
 """
 
 from __future__ import annotations
@@ -106,9 +108,20 @@ class Trainer:
 
     # -------------------------------------------------------------- init
     def init_state(self, key=None) -> TrainState:
+        """Fresh parameters and optimizer state. With a mesh in ``ctx``
+        both are replicated over it (data parallelism: the batch is
+        what gets split)."""
         key = jax.random.key(self._seed) if key is None else key
         params = self.model.init_params(key)
-        return TrainState(params, self.optimizer.init(params), 0)
+        opt_state = self.optimizer.init(params)
+        if self.ctx.mesh is not None:
+            if self.ctx.model_axis is not None:
+                raise NotImplementedError(
+                    "Trainer places data-parallel meshes only: pass a "
+                    "ShardCtx with model_axis=None")
+            params, opt_state = jax.device_put((params, opt_state),
+                                               self.ctx.sharding())
+        return TrainState(params, opt_state, 0)
 
     # -------------------------------------------------------------- step
     def _build_step(self):
@@ -138,18 +151,33 @@ class Trainer:
         donate = (1,) if self.grad_delay else (0, 1)
         return jax.jit(step_fn, donate_argnums=donate)
 
-    def step(self, state: TrainState, batch: Dict[str, Any]):
+    @property
+    def step_program(self):
+        """The jitted step program (built on first use)."""
         if self._step_fn is None:
             self._step_fn = self._build_step()
+        return self._step_fn
+
+    def step_inputs(self, state: TrainState, batch: Dict[str, Any]):
+        """``(args, m, step_seconds)``: the step program's arguments for
+        ``batch`` — this round's participation weights drawn, the batch
+        split over the mesh's data axes when ``ctx`` has a mesh — and
+        the round's participant count and simulated duration."""
         B = batch["tokens"].shape[0]
         if self.straggler is not None:
             mask, m, dur = self.straggler.step()
             weights = participation_example_weights(
                 jnp.asarray(mask), self.n_workers, B)
         else:
-            mask, m, dur = None, self.n_workers, 0.0
+            m, dur = self.n_workers, 0.0
             weights = None
-        batch = {k: jnp.asarray(v) for k, v in batch.items()}
+        if self.ctx.mesh is not None:
+            split = self.ctx.sharding(self.ctx.dp)
+            batch = jax.device_put(dict(batch), split)
+            if weights is not None:
+                weights = jax.device_put(weights, split)
+        else:
+            batch = {k: jnp.asarray(v) for k, v in batch.items()}
         if self.grad_delay:
             self._param_fifo.append(state.params)
             grad_params = self._param_fifo[0]
@@ -157,9 +185,13 @@ class Trainer:
                 self._param_fifo.popleft()
         else:
             grad_params = None
-        params, opt_state, metrics = self._step_fn(
-            state.params, state.opt_state, batch, weights,
-            jnp.asarray(state.step, jnp.int32), grad_params)
+        args = (state.params, state.opt_state, batch, weights,
+                jnp.asarray(state.step, jnp.int32), grad_params)
+        return args, m, dur
+
+    def step(self, state: TrainState, batch: Dict[str, Any]):
+        args, m, dur = self.step_inputs(state, batch)
+        params, opt_state, metrics = self.step_program(*args)
         return (TrainState(params, opt_state, state.step + 1),
                 metrics, m, dur)
 

@@ -190,3 +190,33 @@ def test_full_configs_match_assignment_card():
     kc = get_config("kimi-k2-1t-a32b")
     assert 0.9e12 < kc.param_count() < 1.2e12
     assert 25e9 < kc.active_param_count() < 40e9
+
+
+def test_bf16_embedding_grad_accumulates_every_token():
+    """A bf16 token table's gradient sums every occurrence of a token:
+    4096 occurrences of one token give 4096 × its cotangent, where a
+    bf16 scatter-add stops growing at 256 (each further 1 rounds away).
+    On a data mesh that error differs with the per-device batch share,
+    so the masked data-parallel step would not match one device."""
+    import dataclasses
+
+    from repro.sharding.specs import ShardCtx
+
+    cfg = dataclasses.replace(
+        reduced(get_config("nanogpt-paper"), d_model=64, vocab=256),
+        dtype="bfloat16")
+    m = build_model(cfg)
+    params = m.init_params(jax.random.key(0))
+    tokens = jnp.zeros((8, 512), jnp.int32)
+
+    def total(table):
+        p = {**params, "embed": {**params["embed"], "embed": table}}
+        return m._embed(p, tokens, ShardCtx.null()).astype(
+            jnp.float32).sum()
+
+    g = jax.grad(total)(params["embed"]["embed"])
+    assert g.dtype == jnp.bfloat16
+    want = 8 * 512 * cfg.d_model ** 0.5     # every entry of row 0
+    np.testing.assert_allclose(np.asarray(g[0], np.float32), want,
+                               rtol=2 ** -8)
+    assert not np.any(np.asarray(g[1:], np.float32))

@@ -1,8 +1,9 @@
-"""ISSUE 8: robustness layer tests — the graceful-degradation ladder
-(forced jax failure completes on a host engine with a routing record),
+"""Robustness layer tests — the graceful-degradation ladder of
+``backend="fastest"`` (an engine failure completes on the next rung with
+a routing record; a forced device backend raises instead),
 crash-safe checkpoint/resume (kill after k of N points, resume, final
 JSON byte-identical), atomic artifact writes, the cost-constants
-warning, the per-bucket sharded-sweep retry, and the ROB001/ROB002
+warning, the sharded-sweep failure contract, and the ROB001/ROB002
 analyzer rules (good/bad fixture twins + the live tree staying clean).
 """
 
@@ -31,9 +32,19 @@ def test_ladder_order_and_exposure():
     assert ENGINE_LADDER == ("jax_sharded", "jax", "vectorized", "serial")
 
 
+def _route_to(monkeypatch, engine):
+    """Make ``backend="fastest"`` pick ``engine`` for every point."""
+    import repro.core.batch as batch_mod
+
+    monkeypatch.setattr(batch_mod, "_route_fastest",
+                        lambda *a, **k: (engine, {"chosen": engine,
+                                                  "reason": "test"}))
+
+
 def test_forced_jax_failure_falls_back_with_routing_record(monkeypatch):
-    """ISSUE 8 acceptance: a forced jax engine failure completes via the
-    downgrade ladder and the downgrade is recorded in routing."""
+    """A forced jax engine failure raises (no host engine takes over);
+    the same failure under ``fastest`` retries once, completes on the
+    next rung, and records the downgrade in routing."""
     calls = {"n": 0}
 
     def boom(*args, **kwargs):
@@ -42,9 +53,15 @@ def test_forced_jax_failure_falls_back_with_routing_record(monkeypatch):
 
     monkeypatch.setattr(bj, "simulate_batch_jax", boom)
     model = exponential_times(1.0, 6)
+    with pytest.raises(RuntimeError, match="injected engine failure"):
+        simulate_batch(("msync", {"m": 2}), model, K=20, seeds=4,
+                       backend="jax")
+    assert calls["n"] == 1                      # no retry, no downgrade
+
+    _route_to(monkeypatch, "jax")
     tb = simulate_batch(("msync", {"m": 2}), model, K=20, seeds=4,
-                        backend="jax")
-    assert calls["n"] == 2                      # retry-once before downgrade
+                        backend="fastest")
+    assert calls["n"] == 3                      # retry-once before downgrade
     assert tb.backend == "vectorized"           # next eligible rung
     downs = tb.routing[0]["downgrades"]
     assert downs == [{"from": "jax", "to": "vectorized",
@@ -55,14 +72,18 @@ def test_forced_jax_failure_falls_back_with_routing_record(monkeypatch):
 
 
 def test_forced_jax_failure_reaches_serial_for_noneligible(monkeypatch):
-    """Rennala has no vectorized fast path, so the ladder lands on
-    serial."""
+    """Rennala has no vectorized fast path, so under ``fastest`` the
+    ladder lands on serial; forced, the failure raises."""
     monkeypatch.setattr(bj, "simulate_batch_jax",
                         lambda *a, **k: (_ for _ in ()).throw(
                             RuntimeError("injected")))
     model = exponential_times(1.0, 6)
+    with pytest.raises(RuntimeError, match="injected"):
+        simulate_batch(("rennala", {"batch": 2}), model, K=15, seeds=3,
+                       backend="jax")
+    _route_to(monkeypatch, "jax")
     tb = simulate_batch(("rennala", {"batch": 2}), model, K=15, seeds=3,
-                        backend="jax")
+                        backend="fastest")
     assert tb.backend == "serial"
     assert tb.routing[0]["downgrades"][0]["to"] == "serial"
 
@@ -77,8 +98,9 @@ def test_ladder_preserves_contract_errors(monkeypatch):
 
 
 def test_exhausted_ladder_reraises(monkeypatch):
-    """When every rung fails the last exception propagates (after the
-    downgrade records were written along the way)."""
+    """When every rung of the ``fastest`` ladder fails the last
+    exception propagates (after the downgrade records were written
+    along the way)."""
     import repro.core.strategies as strategies_mod
 
     monkeypatch.setattr(bj, "simulate_batch_jax",
@@ -91,14 +113,18 @@ def test_exhausted_ladder_reraises(monkeypatch):
     monkeypatch.setattr(batch_mod, "simulate",
                         lambda *a, **k: (_ for _ in ()).throw(
                             RuntimeError("serial down")))
+    _route_to(monkeypatch, "jax")
     model = exponential_times(1.0, 4)
     with pytest.raises(RuntimeError, match="serial down"):
         simulate_batch(("rennala", {"batch": 2}), model, K=10, seeds=2,
-                       backend="jax")
+                       backend="fastest")
 
 
 # --------------------------------------------------- per-bucket sweep retry
 def test_sharded_bucket_failure_falls_back_per_point(monkeypatch):
+    """A failing sharded bucket raises out of the sweep and out of a
+    forced ``jax_sharded`` run; under ``fastest`` every deferred point
+    downgrades to the plain jax engine with the record kept."""
     from repro.core.strategies import MSync
     from repro.launch.sweep import SweepPoint, run_sharded_sweep
 
@@ -108,13 +134,21 @@ def test_sharded_bucket_failure_falls_back_per_point(monkeypatch):
     model = exponential_times(1.0, 6)
     points = [SweepPoint(index=0, strategy=MSync(m=2), K=12),
               SweepPoint(index=1, strategy=MSync(m=4), K=12)]
-    out = run_sharded_sweep(points, model, None, seeds=[0, 1])
-    for idx in (0, 1):
-        traces, rec = out[idx]
-        assert len(traces) == 2 and traces[0].total_time > 0
-        assert rec["fallback"] is True
-        assert rec["downgrades"][0]["from"] == "jax_sharded:bucket"
+    with pytest.raises(RuntimeError, match="shard program died"):
+        run_sharded_sweep(points, model, None, seeds=[0, 1])
+    with pytest.raises(RuntimeError, match="shard program died"):
+        simulate_batch("msync", model, K=12, seeds=2,
+                       grid={"m": [2, 4]}, backend="jax_sharded")
+
+    _route_to(monkeypatch, "jax_sharded")
+    tb = simulate_batch("msync", model, K=12, seeds=2, grid={"m": [2, 4]},
+                        backend="fastest")
+    assert tb.backend == "jax"
+    for rec in tb.routing:
+        assert rec["downgrades"][0]["from"] == "jax_sharded"
         assert rec["downgrades"][0]["error"] == "RuntimeError"
+        assert "shard" not in rec
+    assert np.all(tb.total_time > 0)
 
 
 # ------------------------------------------------------- checkpoint / resume

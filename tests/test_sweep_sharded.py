@@ -181,10 +181,10 @@ def test_bucket_keys_fuse_and_split():
     assert g1 != g2
     assert _bucket_key("ringleader", _point(0, "ringleader", gamma=0.1),
                        math=False) == ("ringleader", 30, 0.0)
-    # every jax engine family shards now; the fallback branch survives
-    # only as the safety net for a future non-shardable kind
-    assert _bucket_key(None, _point(5, ("rennala", {"batch": 4})),
-                       math=False) == ("fallback", 5)
+    # every jax engine family shards; a kind without a sharded program
+    # is an error, never a silent per-point fallback
+    with pytest.raises(ValueError, match="no sharded program"):
+        _bucket_key(None, _point(5, ("rennala", {"batch": 4})), math=False)
     for name, kw in [("rennala", {"batch": 4}), ("malenia", {"S": 2.0}),
                      ("ringleader", {})]:
         assert shardable_kind(_point(0, (name, kw)).strategy,

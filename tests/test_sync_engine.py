@@ -81,7 +81,6 @@ def test_masked_group_mean_shard_map():
     if jax.device_count() < 4:
         pytest.skip("needs 4 devices")
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.core import masked_group_mean
     mesh = Mesh(np.array(jax.devices()[:4]), ("dp",))
     grads = jnp.arange(4.0)          # per-group scalar "gradient"
@@ -90,7 +89,7 @@ def test_masked_group_mean_shard_map():
     def f(g, mk):
         return masked_group_mean(g, mk, "dp")
 
-    out = shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
+    out = jax.shard_map(f, mesh=mesh, in_specs=(P("dp"), P("dp")),
                     out_specs=P("dp"))(grads, mask)
     # every group holds the m-sync estimator: (0 + 2)/2 = 1
     np.testing.assert_allclose(np.asarray(out), 1.0)

@@ -219,14 +219,13 @@ def test_finish_times_jax_matches_scalar_to_1e9():
     — under x64, since the engines' float32 default cannot express that
     tolerance."""
     import jax
-    from jax.experimental import enable_x64
 
     for mk in (powers_figure3, powers_figure4):
         model = mk(n=16, seed=0, t_max=60.0)
         w = np.arange(16)
         for t0 in (0.0, 7.3, np.linspace(0.0, 80.0, 16)):  # 80 > grid end
             ref = model.finish_times(w, t0)
-            with enable_x64():
+            with jax.enable_x64(True):
                 got = np.asarray(model.finish_times_jax(
                     np.broadcast_to(np.asarray(t0, dtype=np.float64),
                                     (16,))))
@@ -237,13 +236,13 @@ def test_finish_times_jax_tail_inf_and_worker_branches():
     """v = 0 tail => inf, t0 = inf => inf, and the explicit ``workers``
     indexing the arrival-indexed engine uses — all against the NumPy
     path."""
-    from jax.experimental import enable_x64
+    import jax
 
     grid = np.arange(0.0, 10.0, 0.1)
     powers = np.ones((2, len(grid)))
     powers[1, 50:] = 0.0                 # power dies at t = 5
     m = UniversalModel(grid, powers)
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(m.finish_times_jax(np.array([9.9, 9.0]),
                                             target=5.0))
         ref = m.finish_times([0, 1], np.array([9.9, 9.0]), target=5.0)
