@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - busy / window. Layer: device."""
+
+
+def read(obs):
+    t, w = obs.get("trace"), obs.get("window_s")
+    if not t or not w or not t["devices"]:
+        return None
+    return 1.0 - t["busy_s"] / w
