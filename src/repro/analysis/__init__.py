@@ -6,7 +6,7 @@ table and rationale):
 * :mod:`repro.analysis.rng` — RNG-stream discipline in the engine
   modules (RNG001–RNG003);
 * :mod:`repro.analysis.purity` — jit/scan purity of traced functions
-  (JIT001–JIT005);
+  (JIT001–JIT006);
 * :mod:`repro.analysis.registry` — ``STRATEGIES`` / ``SCENARIOS`` /
   time-model / DESIGN.md §3b coverage-matrix / parity-matrix COVERAGE
   lockstep (REG001–REG007);

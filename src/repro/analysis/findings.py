@@ -40,6 +40,9 @@ RULES: Dict[str, str] = {
     "JIT005": "hard-coded jnp.float32/float64 dtype inside a scanned "
               "engine body (breaks x64 engine-mode parity; derive the "
               "dtype from a carried array)",
+    "JIT006": "telemetry span/counter or profiler TraceAnnotation inside "
+              "a traced function (fires once at trace time; use "
+              "jax.named_scope for device code)",
     "REG001": "strategy registered in STRATEGIES but missing from the "
               "DESIGN.md §3b coverage matrix",
     "REG002": "DESIGN.md §3b matrix row names a strategy that is not "

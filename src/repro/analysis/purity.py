@@ -1,4 +1,4 @@
-"""Jit/scan purity pass: JIT001 – JIT005.
+"""Jit/scan purity pass: JIT001 – JIT006.
 
 Discovery: a function is *traced* when it is (a) decorated with
 ``jax.jit`` (bare or under ``functools.partial``), (b) passed to
@@ -28,7 +28,12 @@ anything assigned from them, to a fixpoint — and flags:
 * JIT005  (x64-strict modules only) a hard-coded ``jnp.float32`` /
   ``jnp.float64`` inside a traced function — the engine dtype must
   derive from a carried array so ``x64=True`` switches the whole
-  program.
+  program;
+* JIT006  a host span or counter (``telemetry.span`` / ``step_span`` /
+  ``sync`` / ``count`` / ``wait`` / ``fetch``, or a
+  ``jax.profiler.TraceAnnotation`` / ``StepTraceAnnotation``) anywhere
+  in a traced function: it fires once, at trace time, and then times
+  or counts nothing (device code is named with ``jax.named_scope``).
 """
 
 from __future__ import annotations
@@ -68,6 +73,9 @@ _TRACED_ARG_SLOTS: Dict[str, Tuple[Tuple[int, ...], bool]] = {
 _SIDE_EFFECT_CALLS = {"print", "breakpoint", "time.time",
                       "time.perf_counter", "time.monotonic",
                       "time.sleep"}
+
+_TELEMETRY_CALLS = {"span", "step_span", "sync", "count", "wait", "fetch"}
+_PROFILER_SPANS = {"TraceAnnotation", "StepTraceAnnotation"}
 
 _HOST_COERCIONS = {"float", "int", "bool", "complex"}
 _HOST_METHODS = {"item", "tolist"}
@@ -256,6 +264,18 @@ def _is_static_test(test: ast.AST) -> bool:
     return False
 
 
+def _host_span(cname: Optional[str]) -> bool:
+    """A call to the telemetry module's spans/counters or to a profiler
+    annotation, however imported."""
+    if not cname:
+        return False
+    parts = cname.split(".")
+    if parts[-1] in _PROFILER_SPANS:
+        return len(parts) == 1 or parts[-2] == "profiler"
+    return (len(parts) >= 2 and parts[-2] == "telemetry"
+            and parts[-1] in _TELEMETRY_CALLS)
+
+
 def run_purity_pass(mod: ModuleSource, x64_strict: bool) -> List[Finding]:
     findings: List[Finding] = []
     for tf in traced_functions(mod):
@@ -270,6 +290,13 @@ def run_purity_pass(mod: ModuleSource, x64_strict: bool) -> List[Finding]:
                         mod.rel, node.lineno, "JIT003",
                         f"{cname}() inside {label} fires at trace time "
                         f"only (and re-fires on every retrace)"))
+                # JIT006: host spans/counters in traced code
+                elif _host_span(cname):
+                    findings.append(Finding(
+                        mod.rel, node.lineno, "JIT006",
+                        f"{cname}() inside {label} fires once at trace "
+                        f"time and times or counts nothing; name device "
+                        f"code with jax.named_scope"))
                 # JIT001: host coercions on traced values
                 elif cname in _HOST_COERCIONS and node.args \
                         and _mentions(node.args[0], derived):

@@ -87,6 +87,7 @@ from typing import Any, Callable, Dict, List, Mapping, Optional, Sequence, Union
 
 import numpy as np
 
+from .. import telemetry
 from .strategies import (AggregationStrategy, MSync, STRATEGIES, Trace,
                          _fast_msync_timing_batch, make_strategy, simulate)
 from .time_models import FixedTimes, TimeModel, UniversalModel, philox_rngs
@@ -715,138 +716,143 @@ def simulate_batch(strategy: StrategySpec,
     used_routing: List[Dict[str, Any]] = []
     sharded_points = []        # (grid index, SweepPoint) → one fused sweep
     for pt in points:
-        sim_kw = {k: pt[k] for k in pt if k in SIM_GRID_KEYS}
-        strat_kw = {**base_kw, **{k: v for k, v in pt.items()
-                                  if k not in SIM_GRID_KEYS}}
-        K_pt = int(sim_kw.pop("K", K))
-        gamma_pt = float(sim_kw.pop("gamma", gamma))
-        re_pt = int(sim_kw.pop("record_every", record_every))
-        tol_pt = sim_kw.pop("tol_grad_sq", tol_grad_sq)
+        with telemetry.span("repro.batch.point") as point:
+            sim_kw = {k: pt[k] for k in pt if k in SIM_GRID_KEYS}
+            strat_kw = {**base_kw, **{k: v for k, v in pt.items()
+                                      if k not in SIM_GRID_KEYS}}
+            K_pt = int(sim_kw.pop("K", K))
+            gamma_pt = float(sim_kw.pop("gamma", gamma))
+            re_pt = int(sim_kw.pop("record_every", record_every))
+            tol_pt = sim_kw.pop("tol_grad_sq", tol_grad_sq)
 
-        strat = factory(**strat_kw)
-        if isinstance(strat, str):     # factory returned a registry name
-            strat = make_strategy(strat)
-        strat.bind(model.n)
+            strat = factory(**strat_kw)
+            if isinstance(strat, str):     # factory returned a registry name
+                strat = make_strategy(strat)
+            strat.bind(model.n)
 
-        if backend == "auto":
-            chosen = "vectorized" if _vectorized_eligible(
-                strat, model, problem, K_pt, tol_pt) else "serial"
-            route_info = {"chosen": chosen, "forced": False,
-                          "reason": "auto: vectorized when eligible",
-                          "engine": _engine_kind(strat) or "event-loop"}
-        elif backend == "fastest":
-            chosen, route_info = _route_fastest(strat, model, problem,
-                                                K_pt, len(seed_list),
-                                                rng_scheme, tol_pt)
-        else:
-            chosen = backend
-            route_info = {"chosen": chosen, "forced": True,
-                          "engine": _engine_kind(strat) or "event-loop"}
-        # contract errors on a forced/chosen backend raise up front, so
-        # the ladder below only ever sees *execution* failures
-        if chosen == "vectorized" and not _vectorized_eligible(
-                strat, model, problem, K_pt, tol_pt):
-            raise ValueError(
-                "vectorized backend needs timing-only m-sync arrival "
-                "semantics")
-        if chosen in ("jax", "jax_sharded"):
-            if tol_pt is not None:
-                raise NotImplementedError(
-                    "tol_grad_sq early exit is not supported by the jax "
-                    "backends (fixed-length scan); use backend='serial'")
-            from .batch_jax import _check_supported
-            _check_supported(strat, model, problem)
+            if backend == "auto":
+                chosen = "vectorized" if _vectorized_eligible(
+                    strat, model, problem, K_pt, tol_pt) else "serial"
+                route_info = {"chosen": chosen, "forced": False,
+                              "reason": "auto: vectorized when eligible",
+                              "engine": _engine_kind(strat) or "event-loop"}
+            elif backend == "fastest":
+                chosen, route_info = _route_fastest(strat, model, problem,
+                                                    K_pt, len(seed_list),
+                                                    rng_scheme, tol_pt)
+            else:
+                chosen = backend
+                route_info = {"chosen": chosen, "forced": True,
+                              "engine": _engine_kind(strat) or "event-loop"}
+            # contract errors on a forced/chosen backend raise up front, so
+            # the ladder below only ever sees *execution* failures
+            if chosen == "vectorized" and not _vectorized_eligible(
+                    strat, model, problem, K_pt, tol_pt):
+                raise ValueError(
+                    "vectorized backend needs timing-only m-sync arrival "
+                    "semantics")
+            if chosen in ("jax", "jax_sharded"):
+                if tol_pt is not None:
+                    raise NotImplementedError(
+                        "tol_grad_sq early exit is not supported by the jax "
+                        "backends (fixed-length scan); use backend='serial'")
+                from .batch_jax import _check_supported
+                _check_supported(strat, model, problem)
 
-        def run_engine(engine, strat=strat, strat_kw=dict(strat_kw),
-                       K_pt=K_pt, gamma_pt=gamma_pt, re_pt=re_pt,
-                       tol_pt=tol_pt):
-            if engine == "vectorized":
-                if rng_scheme == "counter" \
-                        and not isinstance(model, UniversalModel):
-                    rngs = philox_rngs(seed_list)
-                else:
-                    rngs = [np.random.default_rng(s) for s in seed_list]
-                return _fast_msync_timing_batch(strat._m, model, K_pt,
-                                                rngs,
-                                                rng_scheme=rng_scheme)
-            if engine == "jax":
-                from .batch_jax import simulate_batch_jax
-                return simulate_batch_jax(strat, model, K_pt,
-                                          problem=problem, gamma=gamma_pt,
-                                          seeds=seed_list,
-                                          record_every=re_pt,
-                                          use_pallas=use_pallas, x64=x64)
-            return [simulate(factory(**strat_kw), model, K_pt,
-                             problem=problem, gamma=gamma_pt, seed=s,
-                             record_every=re_pt, tol_grad_sq=tol_pt)
-                    for s in seed_list]
+            def run_engine(engine, strat=strat, strat_kw=dict(strat_kw),
+                           K_pt=K_pt, gamma_pt=gamma_pt, re_pt=re_pt,
+                           tol_pt=tol_pt):
+                if engine == "vectorized":
+                    if rng_scheme == "counter" \
+                            and not isinstance(model, UniversalModel):
+                        rngs = philox_rngs(seed_list)
+                    else:
+                        rngs = [np.random.default_rng(s) for s in seed_list]
+                    return _fast_msync_timing_batch(strat._m, model, K_pt,
+                                                    rngs,
+                                                    rng_scheme=rng_scheme)
+                if engine == "jax":
+                    from .batch_jax import simulate_batch_jax
+                    return simulate_batch_jax(strat, model, K_pt,
+                                              problem=problem, gamma=gamma_pt,
+                                              seeds=seed_list,
+                                              record_every=re_pt,
+                                              use_pallas=use_pallas, x64=x64)
+                return [simulate(factory(**strat_kw), model, K_pt,
+                                 problem=problem, gamma=gamma_pt, seed=s,
+                                 record_every=re_pt, tol_grad_sq=tol_pt)
+                        for s in seed_list]
 
-        if chosen == "jax_sharded":
-            from ..launch.sweep import SweepPoint
-            sharded_points.append(
-                (len(traces), SweepPoint(index=len(traces), strategy=strat,
-                                         K=K_pt, gamma=gamma_pt,
-                                         record_every=re_pt),
-                 run_engine, strat, K_pt, tol_pt))
-            row = None             # filled by the fused sweep below
-            actual = chosen
-        elif forced:
-            actual, row = chosen, run_engine(chosen)
-        else:
-            downs = _ladder_below(chosen, strat, model, problem, K_pt,
-                                  tol_pt)
-            actual, row = _run_point_laddered(chosen, run_engine, downs,
-                                              route_info)
-        traces.append(row)
-        used_backends.append(actual)
-        used_schemes.append({"serial": "stream", "jax": "jax.random",
-                             "jax_sharded": "jax.random"
-                             }.get(actual, rng_scheme))
-        used_routing.append(route_info)
+            if chosen == "jax_sharded":
+                from ..launch.sweep import SweepPoint
+                sharded_points.append(
+                    (len(traces), SweepPoint(index=len(traces), strategy=strat,
+                                             K=K_pt, gamma=gamma_pt,
+                                             record_every=re_pt),
+                     run_engine, strat, K_pt, tol_pt))
+                row = None             # filled by the fused sweep below
+                actual = chosen
+            elif forced:
+                actual, row = chosen, run_engine(chosen)
+            else:
+                downs = _ladder_below(chosen, strat, model, problem, K_pt,
+                                      tol_pt)
+                actual, row = _run_point_laddered(chosen, run_engine, downs,
+                                                  route_info)
+            traces.append(row)
+            used_backends.append(actual)
+            used_schemes.append({"serial": "stream", "jax": "jax.random",
+                                 "jax_sharded": "jax.random"
+                                 }.get(actual, rng_scheme))
+            used_routing.append(route_info)
+            point.annotate(engine=actual)
 
     if sharded_points:
-        # ONE fused, shape-bucketed, shard_mapped launch for every grid
-        # point routed to the sharded sweep backend. Forced: a failure
-        # raises. Routed by fastest: retry once, then each deferred
-        # point falls down the ladder from "jax"
-        from ..launch.sweep import run_sharded_sweep
+        # one more point span: the fused run of every point routed here
+        with telemetry.span("repro.batch.point", engine="jax_sharded",
+                            points=len(sharded_points)):
+            # ONE fused, shape-bucketed, shard_mapped launch for every grid
+            # point routed to the sharded sweep backend. Forced: a failure
+            # raises. Routed by fastest: retry once, then each deferred
+            # point falls down the ladder from "jax"
+            from ..launch.sweep import run_sharded_sweep
 
-        def run_sweep():
-            return run_sharded_sweep(
-                [sp for _, sp, *_ in sharded_points], model, problem,
-                seed_list, use_pallas=use_pallas, x64=x64)
+            def run_sweep():
+                return run_sharded_sweep(
+                    [sp for _, sp, *_ in sharded_points], model, problem,
+                    seed_list, use_pallas=use_pallas, x64=x64)
 
-        results = fused_exc = None
-        if forced:
-            results = run_sweep()
-        else:
-            for _attempt in range(2):
-                try:
-                    results = run_sweep()
-                    break
-                except Exception as exc:
-                    fused_exc = exc
-        if results is not None:
-            for g, *_ in sharded_points:
-                row, shard_rec = results[g]
-                traces[g] = row
-                used_routing[g] = {**used_routing[g], "shard": shard_rec}
-        else:
-            for g, _sp, run_engine, strat, K_pt, tol_pt in sharded_points:
-                route_info = used_routing[g]
-                route_info.setdefault("downgrades", []).append({
-                    "from": "jax_sharded", "to": "jax",
-                    "error": type(fused_exc).__name__,
-                    "reason": str(fused_exc)[:300], "retried": True})
-                downs = _ladder_below("jax", strat, model, problem, K_pt,
-                                      tol_pt)
-                actual, row = _run_point_laddered("jax", run_engine,
-                                                  downs, route_info)
-                traces[g] = row
-                used_backends[g] = actual
-                used_schemes[g] = {"serial": "stream",
-                                   "jax": "jax.random"}.get(actual,
-                                                            rng_scheme)
+            results = fused_exc = None
+            if forced:
+                results = run_sweep()
+            else:
+                for _attempt in range(2):
+                    try:
+                        results = run_sweep()
+                        break
+                    except Exception as exc:
+                        fused_exc = exc
+            if results is not None:
+                for g, *_ in sharded_points:
+                    row, shard_rec = results[g]
+                    traces[g] = row
+                    used_routing[g] = {**used_routing[g], "shard": shard_rec}
+            else:
+                for g, _sp, run_engine, strat, K_pt, tol_pt in sharded_points:
+                    route_info = used_routing[g]
+                    route_info.setdefault("downgrades", []).append({
+                        "from": "jax_sharded", "to": "jax",
+                        "error": type(fused_exc).__name__,
+                        "reason": str(fused_exc)[:300], "retried": True})
+                    downs = _ladder_below("jax", strat, model, problem, K_pt,
+                                          tol_pt)
+                    actual, row = _run_point_laddered("jax", run_engine,
+                                                      downs, route_info)
+                    traces[g] = row
+                    used_backends[g] = actual
+                    used_schemes[g] = {"serial": "stream",
+                                       "jax": "jax.random"}.get(actual,
+                                                                rng_scheme)
 
     # auto can pick different backends per grid point; report faithfully
     backend_label = used_backends[0] if len(set(used_backends)) == 1 \

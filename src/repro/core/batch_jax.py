@@ -93,6 +93,8 @@ from typing import Callable, List, Optional, Sequence
 
 import numpy as np
 
+from .. import telemetry
+from ..telemetry import ENGINE_DISPATCH, ENGINE_PREP
 from .strategies import (AggregationStrategy, Async, Malenia, MSync,
                          OptimalASGD, Rennala, Ringleader, Ringmaster,
                          Trace)
@@ -235,28 +237,33 @@ def _check_supported(strategy: AggregationStrategy, model, problem) -> str:
 
 
 def _timing_round(ft, ver, comp, k, cand, m, use_pallas):
-    """Shared m-sync round update on ``(S, n)`` state (see module doc)."""
+    """Shared m-sync round update on ``(S, n)`` state (see module doc).
+    The selection (m-th order statistic and tie rank) runs under the
+    ``order_stat`` name scope."""
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
     from ..kernels.order_stats import mth_smallest
 
     stale = ver < k
-    T = mth_smallest(cand, m, use_pallas=use_pallas)
-    leq = cand <= T[:, None]
+    with jax.named_scope("order_stat"):
+        T = mth_smallest(cand, m, use_pallas=use_pallas)
+        leq = cand <= T[:, None]
 
-    def exact_acc(_):
-        # ties straddle the m-boundary somewhere: rank tied candidates by
-        # worker index and accept only up to the per-row quota (cumsum is
-        # ~40% of the round cost, so it only runs on tie rounds)
-        c_lt = (cand < T[:, None]).sum(axis=1)
-        tie = cand == T[:, None]
-        tie_rank = jnp.cumsum(tie, axis=1) - 1
-        return (cand < T[:, None]) | (tie
-                                      & (tie_rank < (m - c_lt)[:, None]))
+        def exact_acc(_):
+            # ties straddle the m-boundary somewhere: rank tied candidates
+            # by worker index and accept only up to the per-row quota
+            # (cumsum is ~40% of the round cost, so it only runs on tie
+            # rounds)
+            c_lt = (cand < T[:, None]).sum(axis=1)
+            tie = cand == T[:, None]
+            tie_rank = jnp.cumsum(tie, axis=1) - 1
+            return (cand < T[:, None]) | (tie
+                                          & (tie_rank < (m - c_lt)[:, None]))
 
-    acc = lax.cond(jnp.all(leq.sum(axis=1) == m),
-                   lambda _: leq, exact_acc, operand=None)
+        acc = lax.cond(jnp.all(leq.sum(axis=1) == m),
+                       lambda _: leq, exact_acc, operand=None)
     popped = stale & (ft < T[:, None])
     # int32 sums: under x64 bool sums default to int64 and would promote
     # the carried counters out of their scan-carry dtype
@@ -278,24 +285,26 @@ def _timing_round_rowwise(ft, ver, comp, k, cand, m_vec):
     exactly the ``leq`` mask, so whichever branch the (per-shard local)
     ``lax.cond`` takes, the accept mask is identical.
     """
+    import jax
     import jax.numpy as jnp
     from jax import lax
 
     from ..kernels.order_stats import mth_smallest_rowwise
 
     stale = ver < k
-    T = mth_smallest_rowwise(cand, m_vec)
-    leq = cand <= T[:, None]
+    with jax.named_scope("order_stat"):
+        T = mth_smallest_rowwise(cand, m_vec)
+        leq = cand <= T[:, None]
 
-    def exact_acc(_):
-        c_lt = (cand < T[:, None]).sum(axis=1)
-        tie = cand == T[:, None]
-        tie_rank = jnp.cumsum(tie, axis=1) - 1
-        return (cand < T[:, None]) | (tie
-                                      & (tie_rank < (m_vec - c_lt)[:, None]))
+        def exact_acc(_):
+            c_lt = (cand < T[:, None]).sum(axis=1)
+            tie = cand == T[:, None]
+            tie_rank = jnp.cumsum(tie, axis=1) - 1
+            return (cand < T[:, None]) | (
+                tie & (tie_rank < (m_vec - c_lt)[:, None]))
 
-    acc = lax.cond(jnp.all(leq.sum(axis=1) == m_vec),
-                   lambda _: leq, exact_acc, operand=None)
+        acc = lax.cond(jnp.all(leq.sum(axis=1) == m_vec),
+                       lambda _: leq, exact_acc, operand=None)
     popped = stale & (ft < T[:, None])
     comp = comp + m_vec + popped.sum(axis=1, dtype=jnp.int32)
     ft = jnp.where(popped, cand, ft)
@@ -449,10 +458,11 @@ def _general_run(model, problem, m, n, S, K, gamma, use_pallas, seeds):
     from jax import lax
 
     math = problem is not None
-    keys0, x_init = _keys_and_x(problem, S, n, seeds)
-    finish_all = _finish_factory(model, S, n)
-    if math:
-        grad_mean = _grad_mean_fn(problem, m)
+    with telemetry.span(ENGINE_PREP):
+        keys0, x_init = _keys_and_x(problem, S, n, seeds)
+        finish_all = _finish_factory(model, S, n)
+        if math:
+            grad_mean = _grad_mean_fn(problem, m)
 
     def step(carry, k):
         ft, ver, comp, x, keys = carry
@@ -484,7 +494,34 @@ def _general_run(model, problem, m, n, S, K, gamma, use_pallas, seeds):
             step, init, jnp.arange(K, dtype=jnp.int32))
         return comp, x, T, val, gn
 
-    return jax.block_until_ready(run(keys0))
+    with telemetry.span(ENGINE_DISPATCH):
+        out = run(keys0)
+    return telemetry.wait(out)
+
+
+def _aot_run(key, wrapped, args, meta):
+    """Run the sweep program cached under ``key`` in :data:`_SWEEP_PROGS`
+    (lowering and compiling ``wrapped`` for ``args`` first on a miss)
+    and wait for its outputs. ``meta`` (if given) receives
+    ``cache_hit``, ``compile_s`` (the compile's dispatch span) and
+    ``exec_s`` (the call's dispatch and wait spans)."""
+    import jax
+
+    hit = key in _SWEEP_PROGS
+    compile_s = 0.0
+    if not hit:
+        with telemetry.span(ENGINE_DISPATCH, phase="compile") as built:
+            compiled = jax.jit(wrapped).lower(*args).compile()
+        compile_s = built.seconds
+        _prog_cache_put(_SWEEP_PROGS, key, compiled)
+    with telemetry.span(ENGINE_DISPATCH) as run:
+        out = _SWEEP_PROGS[key](*args)
+    with telemetry.sync() as done:
+        out = jax.block_until_ready(out)
+    if meta is not None:
+        meta.update(cache_hit=hit, compile_s=round(compile_s, 4),
+                    exec_s=round(run.seconds + done.seconds, 4))
+    return out
 
 
 class _ById:
@@ -538,21 +575,20 @@ def sharded_msync_run(model, problem, n, S, K, seeds, m_list, gamma_list,
     counting selection always runs the fused elementwise path — the
     selected value is the same element either way.
     """
-    import time
-
     import jax
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec
 
     math = problem is not None
-    keys0, x_init = _keys_and_x(problem, S, n, seeds)
     m_static = int(m_list[0]) if math else None
-    if math:
-        grad_mean = _grad_mean_fn(problem, m_static)
-    dt = _engine_dtype()
-    m_in = jnp.asarray(m_list, jnp.int32)
-    g_in = jnp.asarray(gamma_list, dt)
+    with telemetry.span(ENGINE_PREP):
+        keys0, x_init = _keys_and_x(problem, S, n, seeds)
+        if math:
+            grad_mean = _grad_mean_fn(problem, m_static)
+        dt = _engine_dtype()
+        m_in = jnp.asarray(m_list, jnp.int32)
+        g_in = jnp.asarray(gamma_list, dt)
 
     def unit_prog(keys, m_vec, gamma_vec, x0):
         U = keys.shape[0]                     # local block under shard_map
@@ -599,20 +635,7 @@ def sharded_msync_run(model, problem, n, S, K, seeds, m_list, gamma_list,
     key = ("msync", math, m_static, n, S, K,
            bool(jax.config.jax_enable_x64), _mesh_cache_key(mesh),
            _ById(model), _ById(problem))
-    hit = key in _SWEEP_PROGS
-    args = (keys0, m_in, g_in, x_init)
-    compile_s = 0.0
-    if not hit:
-        t0 = time.perf_counter()
-        compiled = jax.jit(wrapped).lower(*args).compile()
-        compile_s = time.perf_counter() - t0
-        _prog_cache_put(_SWEEP_PROGS, key, compiled)
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(_SWEEP_PROGS[key](*args))
-    if meta is not None:
-        meta.update(cache_hit=hit, compile_s=round(compile_s, 4),
-                    exec_s=round(time.perf_counter() - t0, 4))
-    return out
+    return _aot_run(key, wrapped, (keys0, m_in, g_in, x_init), meta)
 
 
 def _rennala_run(model, problem, B, n, S, K, gamma, use_pallas, seeds,
@@ -633,8 +656,6 @@ def _rennala_run(model, problem, B, n, S, K, gamma, use_pallas, seeds,
     function of its own ``PRNGKey``, so sharded outputs are bitwise the
     unsharded ``backend="jax"`` outputs. ``meta`` (if given) receives
     ``cache_hit``/``compile_s``/``exec_s``."""
-    import time
-
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -643,7 +664,8 @@ def _rennala_run(model, problem, B, n, S, K, gamma, use_pallas, seeds,
     from ..kernels.order_stats import mth_smallest
 
     math = problem is not None
-    keys0, x_init = _keys_and_x(problem, S, n, seeds)
+    with telemetry.span(ENGINE_PREP):
+        keys0, x_init = _keys_and_x(problem, S, n, seeds)
 
     def unit_prog(keys, x0):
         U = keys.shape[0]                     # local block under shard_map
@@ -696,7 +718,9 @@ def _rennala_run(model, problem, B, n, S, K, gamma, use_pallas, seeds,
         return comp, x, T, val, gn
 
     if mesh is None:
-        return jax.block_until_ready(jax.jit(unit_prog)(keys0, x_init))
+        with telemetry.span(ENGINE_DISPATCH):
+            out = jax.jit(unit_prog)(keys0, x_init)
+        return telemetry.wait(out)
 
     P = PartitionSpec
     wrapped = jax.shard_map(
@@ -708,20 +732,7 @@ def _rennala_run(model, problem, B, n, S, K, gamma, use_pallas, seeds,
     key = ("rennala", math, B, n, S, K, float(gamma), bool(use_pallas),
            bool(jax.config.jax_enable_x64), _mesh_cache_key(mesh),
            _ById(model), _ById(problem))
-    hit = key in _SWEEP_PROGS
-    args = (keys0, x_init)
-    compile_s = 0.0
-    if not hit:
-        t0 = time.perf_counter()
-        compiled = jax.jit(wrapped).lower(*args).compile()
-        compile_s = time.perf_counter() - t0
-        _prog_cache_put(_SWEEP_PROGS, key, compiled)
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(_SWEEP_PROGS[key](*args))
-    if meta is not None:
-        meta.update(cache_hit=hit, compile_s=round(compile_s, 4),
-                    exec_s=round(time.perf_counter() - t0, 4))
-    return out
+    return _aot_run(key, wrapped, (keys0, x_init), meta)
 
 
 def _malenia_grad_fn(problem, n, L):
@@ -794,8 +805,6 @@ def _malenia_run(model, problem, S_target, n, S, K, gamma, seeds,
     bitwise the unsharded ones); ``meta`` (if given) receives
     ``cache_hit``/``compile_s``/``exec_s``.
     """
-    import time
-
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -814,7 +823,8 @@ def _malenia_run(model, problem, S_target, n, S, K, gamma, seeds,
     if L < ceilS:
         raise ValueError(f"chain_len={L} cannot certify harmonic mean "
                          f"S={S_target} (need >= {ceilS})")
-    keys0, x_init = _keys_and_x(problem, S, n, seeds)
+    with telemetry.span(ENGINE_PREP):
+        keys0, x_init = _keys_and_x(problem, S, n, seeds)
 
     def P_of_counts(B):
         ok1 = jnp.all(B >= 1, axis=-1)
@@ -941,7 +951,9 @@ def _malenia_run(model, problem, S_target, n, S, K, gamma, seeds,
             return comp, used, x, T, val, gn, bad
 
         if mesh is None:
-            return jax.block_until_ready(jax.jit(unit_prog)(keys0, x_init))
+            with telemetry.span(ENGINE_DISPATCH):
+                out = jax.jit(unit_prog)(keys0, x_init)
+            return telemetry.wait(out)
 
         P = PartitionSpec
         wrapped = jax.shard_map(
@@ -953,24 +965,11 @@ def _malenia_run(model, problem, S_target, n, S, K, gamma, seeds,
         key = ("malenia", math, float(S_target), L, n, S, K, float(gamma),
                bool(jax.config.jax_enable_x64), _mesh_cache_key(mesh),
                _ById(model), _ById(problem))
-        hit = key in _SWEEP_PROGS
-        args = (keys0, x_init)
-        compile_s = 0.0
-        if not hit:
-            t0 = time.perf_counter()
-            compiled = jax.jit(wrapped).lower(*args).compile()
-            compile_s = time.perf_counter() - t0
-            _prog_cache_put(_SWEEP_PROGS, key, compiled)
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(_SWEEP_PROGS[key](*args))
-        if meta is not None:
-            meta.update(cache_hit=hit, compile_s=round(compile_s, 4),
-                        exec_s=round(time.perf_counter() - t0, 4))
-        return out
+        return _aot_run(key, wrapped, (keys0, x_init), meta)
 
     for _ in range(4):
         comp, used, x, T, val, gn, bad = attempt(L)
-        if not bool(np.any(np.asarray(bad))):
+        if not bool(np.any(telemetry.fetch(bad))):
             return comp, x, T, val, gn, used
         L *= 2                                    # outran the chains: retry
     raise RuntimeError(
@@ -1057,8 +1056,6 @@ def _ringleader_run(model, problem, n, S, K, gamma, seeds, chain_len=None,
     ``fold_in(round_key, j)``). With a ``mesh`` the chunk program is
     ``shard_map``ped over the seed rows; ``meta`` (if given) collects
     chain/window/chunk accounting and program-cache hits."""
-    import time
-
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -1067,22 +1064,23 @@ def _ringleader_run(model, problem, n, S, K, gamma, seeds, chain_len=None,
     from .time_models import ragged_layout
 
     math = problem is not None
-    if chain_len:
-        budgets = np.full(n, int(chain_len), np.int64)
-    else:
-        # expected global arrivals per round: every worker delivers ~
-        # rate_i / min(rate) times while the slowest delivers once
-        rates = _model_rates(model)
-        per_round = float(rates.sum() / max(rates.min(), 1e-12))
-        fluct = (1.0 if isinstance(model, (FixedTimes, UniversalModel))
-                 else 1.0 + float(np.log(max(n, 1))))
-        budgets = _chain_plan_ragged(
-            model, n, int(np.ceil(K * per_round * fluct)))
-    keys0, x_init = _keys_and_x(problem, S, n, seeds)
-    sub0 = jax.vmap(lambda kk: jax.random.split(kk, 2))(keys0)
-    round_root, chain_root = sub0[:, 0], sub0[:, 1]
-    upd_fn = _ringleader_grad_fn(problem, n) if math else None
-    dt = _engine_dtype()
+    with telemetry.span(ENGINE_PREP):
+        if chain_len:
+            budgets = np.full(n, int(chain_len), np.int64)
+        else:
+            # expected global arrivals per round: every worker delivers ~
+            # rate_i / min(rate) times while the slowest delivers once
+            rates = _model_rates(model)
+            per_round = float(rates.sum() / max(rates.min(), 1e-12))
+            fluct = (1.0 if isinstance(model, (FixedTimes, UniversalModel))
+                     else 1.0 + float(np.log(max(n, 1))))
+            budgets = _chain_plan_ragged(
+                model, n, int(np.ceil(K * per_round * fluct)))
+        keys0, x_init = _keys_and_x(problem, S, n, seeds)
+        sub0 = jax.vmap(lambda kk: jax.random.split(kk, 2))(keys0)
+        round_root, chain_root = sub0[:, 0], sub0[:, 1]
+        upd_fn = _ringleader_grad_fn(problem, n) if math else None
+        dt = _engine_dtype()
 
     # windowed ragged chain state (host): canonical per-worker pool
     # segments, drawn slot counts, carried last-absolute-time anchors
@@ -1096,22 +1094,26 @@ def _ringleader_run(model, problem, n, S, K, gamma, seeds, chain_len=None,
     def draw_to(budgets_new):
         nonlocal drawn, anchors
         ext = budgets_new - drawn
-        builder = _chain_builder(model, S, n, ext, starts=drawn, mesh=mesh)
-        t0 = time.perf_counter()
-        flat_ext, anchors = builder(chain_root, anchors)
-        flat_ext = jax.block_until_ready(flat_ext)
+        with telemetry.span(ENGINE_PREP):
+            builder = _chain_builder(model, S, n, ext, starts=drawn,
+                                     mesh=mesh)
+        with telemetry.span(ENGINE_DISPATCH) as run:
+            flat_ext, anchors = builder(chain_root, anchors)
+        with telemetry.sync() as done:
+            flat_ext = jax.block_until_ready(flat_ext)
         if meta is not None:
             meta["chain_s"] = round(
-                meta["chain_s"] + time.perf_counter() - t0, 4)
+                meta["chain_s"] + run.seconds + done.seconds, 4)
             meta["windows"] += 1
             meta["drawn_slots"].append(int(ext.sum()))
-        ext_np = np.asarray(flat_ext)
-        eoff, _, _, _ = ragged_layout(ext, drawn)
-        for i in range(n):
-            segs[i] = np.concatenate(
-                [segs[i], ext_np[:, eoff[i]:eoff[i] + ext[i]]], axis=1)
-        drawn = budgets_new.copy()
-        return jnp.asarray(np.concatenate(segs, axis=1))
+        ext_np = telemetry.fetch(flat_ext)
+        with telemetry.span(ENGINE_PREP):
+            eoff, _, _, _ = ragged_layout(ext, drawn)
+            for i in range(n):
+                segs[i] = np.concatenate(
+                    [segs[i], ext_np[:, eoff[i]:eoff[i] + ext[i]]], axis=1)
+            drawn = budgets_new.copy()
+            return jnp.asarray(np.concatenate(segs, axis=1))
 
     def chunk_prog(buds, Kc):
         offs, widx_flat, _, _ = ragged_layout(buds)
@@ -1192,11 +1194,15 @@ def _ringleader_run(model, problem, n, S, K, gamma, seeds, chain_len=None,
     grows = 0
     while done < K:
         kc = min(Kc, K - done)
-        out = jax.block_until_ready(chunk_prog(drawn, kc)(ch_flat, *carry))
+        with telemetry.span(ENGINE_PREP):
+            prog = chunk_prog(drawn, kc)
+        with telemetry.span(ENGINE_DISPATCH):
+            out = prog(ch_flat, *carry)
+        out = telemetry.wait(out)
         if meta is not None:
             meta["chunk_runs"] += 1
         p, comp, x_prev, x_cur, trig, rkeys, bad, T, val, gn = out
-        if bool(np.any(np.asarray(bad))):
+        if bool(np.any(telemetry.fetch(bad))):
             # pool may hide arrivals inside this chunk: discard its
             # outputs, double the budgets, draw ONLY the extension and
             # re-run the SAME chunk from the saved chunk-start carry
@@ -1210,13 +1216,14 @@ def _ringleader_run(model, problem, n, S, K, gamma, seeds, chain_len=None,
             grows += 1
             ch_flat = draw_to(drawn * 2)
             continue
-        T_all[done:done + kc] = np.asarray(T)
         if math:
-            vals[done:done + kc] = np.asarray(val)
-            gns[done:done + kc] = np.asarray(gn)
+            T_all[done:done + kc], vals[done:done + kc], \
+                gns[done:done + kc] = telemetry.fetch((T, val, gn))
+        else:
+            T_all[done:done + kc] = telemetry.fetch(T)
         carry = (p, comp, x_prev, x_cur, trig, rkeys)
         done += kc
-    comp_np = np.asarray(carry[1])
+    comp_np = telemetry.fetch(carry[1])
     x = carry[3]
     return comp_np, x, T_all, vals, gns, comp_np  # waste-free: used == comp
 
@@ -1624,8 +1631,6 @@ def _chain_scan_run(model, problem, ringmaster, max_delay, delay_adaptive,
     ``meta`` (if given) collects chain/scan wall times, program-cache
     hits, window count and draw/scan accounting for the routing
     record."""
-    import time
-
     import jax
     import jax.numpy as jnp
 
@@ -1633,25 +1638,27 @@ def _chain_scan_run(model, problem, ringmaster, max_delay, delay_adaptive,
     from .time_models import ragged_layout
 
     math = problem is not None
-    keys0, x_init = _keys_and_x(problem, S, n, seeds)
-    sub = jax.vmap(lambda kk: jax.random.split(kk, 2))(keys0)
-    gkey_root, chain_root = sub[:, 0], sub[:, 1]
-    if math:
-        xs_init = jnp.broadcast_to(x_init[:, None, :],
-                                   (S, n) + x_init.shape[1:])
+    with telemetry.span(ENGINE_PREP):
+        keys0, x_init = _keys_and_x(problem, S, n, seeds)
+        sub = jax.vmap(lambda kk: jax.random.split(kk, 2))(keys0)
+        gkey_root, chain_root = sub[:, 0], sub[:, 1]
+        if math:
+            xs_init = jnp.broadcast_to(x_init[:, None, :],
+                                       (S, n) + x_init.shape[1:])
 
-    # Async never discards: the window is exactly K. Ringmaster gets the
-    # empirical discard budget (see _ring_pop_budget).
-    budget = _ring_pop_budget(n, K, max_delay) if ringmaster else 0
-    if chain_len:
-        budgets = np.full(n, int(chain_len), np.int64)
-    elif layout == "rect":
-        budgets = np.full(n, _chain_plan(model, n, K + budget), np.int64)
-    elif layout == "ragged":
-        budgets = _chain_plan_ragged(model, n, K + budget)
-    else:
-        raise ValueError(f"unknown chain layout {layout!r}; "
-                         "use 'ragged' or 'rect'")
+        # Async never discards: the window is exactly K. Ringmaster gets
+        # the empirical discard budget (see _ring_pop_budget).
+        budget = _ring_pop_budget(n, K, max_delay) if ringmaster else 0
+        if chain_len:
+            budgets = np.full(n, int(chain_len), np.int64)
+        elif layout == "rect":
+            budgets = np.full(n, _chain_plan(model, n, K + budget),
+                              np.int64)
+        elif layout == "ragged":
+            budgets = _chain_plan_ragged(model, n, K + budget)
+        else:
+            raise ValueError(f"unknown chain layout {layout!r}; "
+                             "use 'ragged' or 'rect'")
     scan_needed = math or ringmaster
     dt = _engine_dtype()
 
@@ -1679,32 +1686,37 @@ def _chain_scan_run(model, problem, ringmaster, max_delay, delay_adaptive,
         # draw ONLY the extension slots, anchored at the carried last
         # absolute times (window 0: everything, anchored at t = 0)
         ext = budgets - drawn
-        builder = _chain_builder(model, S, n, ext, starts=drawn, mesh=mesh)
-        t0 = time.perf_counter()
-        flat_ext, anchors = builder(chain_root, anchors)
-        flat_ext = jax.block_until_ready(flat_ext)
+        with telemetry.span(ENGINE_PREP):
+            builder = _chain_builder(model, S, n, ext, starts=drawn,
+                                     mesh=mesh)
+        with telemetry.span(ENGINE_DISPATCH) as run:
+            flat_ext, anchors = builder(chain_root, anchors)
+        with telemetry.sync() as done_chain:
+            flat_ext = jax.block_until_ready(flat_ext)
         if meta is not None:
             meta["chain_s"] = round(
-                meta["chain_s"] + time.perf_counter() - t0, 4)
+                meta["chain_s"] + run.seconds + done_chain.seconds, 4)
             meta["windows"] += 1
             meta["drawn_slots"].append(int(ext.sum()))
-        ext_np = np.asarray(flat_ext)
-        eoff, _, _, _ = ragged_layout(ext, drawn)
-        for i in range(n):
-            segs[i] = np.concatenate(
-                [segs[i], ext_np[:, eoff[i]:eoff[i] + ext[i]]], axis=1)
-        drawn = budgets.copy()
-        pool = np.concatenate(segs, axis=1)         # canonical (S, total)
-        _, widx_flat, _, total = ragged_layout(drawn)
+        ext_np = telemetry.fetch(flat_ext)
+        with telemetry.span(ENGINE_PREP):
+            eoff, _, _, _ = ragged_layout(ext, drawn)
+            for i in range(n):
+                segs[i] = np.concatenate(
+                    [segs[i], ext_np[:, eoff[i]:eoff[i] + ext[i]]], axis=1)
+            drawn = budgets.copy()
+            pool = np.concatenate(segs, axis=1)     # canonical (S, total)
+            _, widx_flat, _, total = ragged_layout(drawn)
         if meta is not None:
             meta["pool_elems"] = total
 
         # merged global arrival order + certified horizon per seed
-        h = np.asarray(anchors).min(axis=1)         # (S,)
+        h = telemetry.fetch(anchors).min(axis=1)    # (S,)
         A_cap = int(min(K + budget, total))
-        t_seq, idx = smallest_k(jnp.asarray(pool), A_cap)
-        t_host = np.asarray(t_seq)                  # (S, A_cap) ascending
-        w_all = widx_flat[np.asarray(idx)]          # (S, A_cap) worker ids
+        with telemetry.span(ENGINE_DISPATCH):
+            t_seq, idx = smallest_k(jnp.asarray(pool), A_cap)
+        t_host, idx = telemetry.fetch((t_seq, idx))  # (S, A_cap) ascending
+        w_all = widx_flat[idx]                      # (S, A_cap) worker ids
         done = kfin >= K
         # certified: strictly before the horizon (an arrival AT the
         # horizon could tie with an undrawn slot of the slowest worker)
@@ -1744,17 +1756,13 @@ def _chain_scan_run(model, problem, ringmaster, max_delay, delay_adaptive,
                 comp0 = jnp.zeros(S, jnp.int32)
                 carry = ((k0, ver0, comp0, x_init, xs_init) if math
                          else (k0, ver0, comp0))
-            t0 = time.perf_counter()
             if math:
-                prog = _arrival_math_prog(problem, gamma, delay_adaptive,
-                                          S, n, K, max_delay, mesh=mesh)
-                pos = jnp.asarray(pos_idx.astype(np.int32))
-                kf, ver, cmp_, x_c, xs_c, acc, v_w, g_w = \
-                    jax.block_until_ready(prog(
-                        w_win, valid, pos, gkey_root, *carry))
-                carry = (kf, ver, cmp_, x_c, xs_c)
-                v_w = np.asarray(v_w)
-                g_w = np.asarray(g_w)
+                with telemetry.span(ENGINE_PREP):
+                    prog = _arrival_math_prog(problem, gamma,
+                                              delay_adaptive, S, n, K,
+                                              max_delay, mesh=mesh)
+                    pos = jnp.asarray(pos_idx.astype(np.int32))
+                    args = (w_win, valid, pos, gkey_root, *carry)
             else:
                 scan_key_known = (
                     S, n, K, max_delay, W,
@@ -1763,17 +1771,30 @@ def _chain_scan_run(model, problem, ringmaster, max_delay, delay_adaptive,
                 ) in _SCAN_PROGS
                 if meta is not None:
                     meta["scan_cache_hit"] = scan_key_known
-                kf, ver, cmp_, acc = jax.block_until_ready(
-                    _ring_timing_prog(S, n, K, max_delay, W,
-                                      mesh=mesh)(w_win, valid, *carry))
+                with telemetry.span(ENGINE_PREP):
+                    prog = _ring_timing_prog(S, n, K, max_delay, W,
+                                             mesh=mesh)
+                    args = (w_win, valid, *carry)
+            with telemetry.span(ENGINE_DISPATCH) as run:
+                out = prog(*args)
+            with telemetry.sync() as done_scan:
+                out = jax.block_until_ready(out)
+            scan_s = run.seconds + done_scan.seconds
+            if math:
+                kf, ver, cmp_, x_c, xs_c, acc, v_w, g_w = out
+                carry = (kf, ver, cmp_, x_c, xs_c)
+                with telemetry.sync() as got:
+                    v_w, g_w = jax.device_get((v_w, g_w))
+                scan_s += got.seconds
+            else:
+                kf, ver, cmp_, acc = out
                 carry = (kf, ver, cmp_)
             if meta is not None:
-                meta["scan_s"] = round(
-                    meta["scan_s"] + time.perf_counter() - t0, 4)
+                meta["scan_s"] = round(meta["scan_s"] + scan_s, 4)
                 meta["scan_ranges"].append((p0, p1))
-            kfin = np.asarray(kf).astype(np.int64)
-            comp = np.asarray(cmp_).astype(np.int64)
-            acc = np.asarray(acc)                    # (W, S), valid-gated
+            kfin, comp, acc = telemetry.fetch((kf, cmp_, acc))
+            kfin = kfin.astype(np.int64)
+            comp = comp.astype(np.int64)            # acc: (W, S), gated
             for s in live_seeds:
                 sel = np.flatnonzero(acc[:, s])
                 sel = sel[:K - int(filled[s])]
@@ -1834,9 +1855,10 @@ def _arrival_while_run(model, problem, max_delay, delay_adaptive, n, S, K,
     from .time_models import jax_worker_key_grid
 
     math = problem is not None
-    keys0, x_init = _keys_and_x(problem, S, n, seeds)
-    xs_init = jnp.broadcast_to(x_init[:, None, :],
-                               (S, n) + x_init.shape[1:])
+    with telemetry.span(ENGINE_PREP):
+        keys0, x_init = _keys_and_x(problem, S, n, seeds)
+        xs_init = jnp.broadcast_to(x_init[:, None, :],
+                                   (S, n) + x_init.shape[1:])
 
     fixed = isinstance(model, FixedTimes)
     universal = isinstance(model, UniversalModel)
@@ -1930,8 +1952,10 @@ def _arrival_while_run(model, problem, max_delay, delay_adaptive, n, S, K,
         _, _, _, k, comp, x, _, _, _, Tb, vb, gb = out
         return k, comp, x, Tb.T, vb.T, gb.T      # (K, S) like the scans
 
-    kfin, comp, x, T, val, gn = jax.block_until_ready(run(keys0))
-    if int(np.min(np.asarray(kfin))) < K:
+    with telemetry.span(ENGINE_DISPATCH):
+        out = run(keys0)
+    kfin, comp, x, T, val, gn = telemetry.wait(out)
+    if int(np.min(telemetry.fetch(kfin))) < K:
         raise RuntimeError(
             f"arrival-indexed jax backend hit its {cap}-arrival cap "
             f"before finishing K={K} iterations (max_delay too tight?); "
@@ -2036,9 +2060,10 @@ def simulate_batch_jax(strategy: AggregationStrategy,
                 _fixed_timing_jit = jax.jit(
                     _fixed_timing_run,
                     static_argnames=("S", "m", "K", "use_pallas"))
-            comp, T = jax.block_until_ready(_fixed_timing_jit(
-                jnp.asarray(model.taus), S=S, m=m, K=K,
-                use_pallas=use_pallas))
+            with telemetry.span(ENGINE_DISPATCH):
+                out = _fixed_timing_jit(jnp.asarray(model.taus), S=S, m=m,
+                                        K=K, use_pallas=use_pallas)
+            comp, T = telemetry.wait(out)
             x = val = gn = None
         else:
             comp, x, T, val, gn = _general_run(model, problem, m, n, S, K,
@@ -2081,38 +2106,41 @@ def assemble_traces(comp, x, T, val, gn, used, S, K, record_every,
     """Package raw engine outputs (``comp (S,)``, ``T/val/gn (K, S)``,
     ``x (S, d)``) into the per-seed :class:`Trace` list — shared by
     :func:`simulate_batch_jax` and the sharded sweep backend, so both
-    paths produce structurally identical traces from identical arrays."""
+    paths produce structurally identical traces from identical arrays.
+    Every device array comes to the host in one fetch."""
     import jax.numpy as jnp
 
     math = problem is not None
-    comp = np.asarray(comp)
-    T = np.asarray(T)                             # (K, S)
-    used = np.broadcast_to(np.asarray(used), (S,))  # malenia: per seed
-    total = T[-1]
-    traces: List[Trace] = []
-    if math:
-        val = np.asarray(val)
-        gn = np.asarray(gn)
-        x_np = np.asarray(x)
-        rec = np.arange(record_every, K + 1, record_every)     # steps k
-        x0j = jnp.asarray(problem.x0, dtype=_engine_dtype())
-        f0 = float(problem.f(x0j))
-        g0 = np.asarray(problem.grad(x0j))
-        gn0 = float(np.dot(g0, g0))
-        for s in range(S):
-            times = np.concatenate([[0.0], T[rec - 1, s]])
-            vals = np.concatenate([[f0], val[rec - 1, s]])
-            gns = np.concatenate([[gn0], gn[rec - 1, s]])
-            traces.append(Trace(times, vals, gns, iterations=K,
-                                total_time=float(total[s]),
-                                gradients_used=int(used[s]),
-                                gradients_computed=int(comp[s]),
-                                x_final=x_np[s]))
-    else:
-        e = np.array([])
-        for s in range(S):
-            traces.append(Trace(e, e, e, iterations=K,
-                                total_time=float(total[s]),
-                                gradients_used=int(used[s]),
-                                gradients_computed=int(comp[s])))
+    with telemetry.span("repro.batch.assemble"):
+        if math:
+            x0j = jnp.asarray(problem.x0, dtype=_engine_dtype())
+            comp, T, used, val, gn, x, f0, g0 = telemetry.fetch(
+                (comp, T, used, val, gn, x, problem.f(x0j),
+                 problem.grad(x0j)))
+        else:
+            comp, T, used = telemetry.fetch((comp, T, used))
+        T = np.asarray(T)                         # (K, S)
+        used = np.broadcast_to(np.asarray(used), (S,))  # malenia: per seed
+        total = T[-1]
+        traces: List[Trace] = []
+        if math:
+            rec = np.arange(record_every, K + 1, record_every)  # steps k
+            g0 = np.asarray(g0)
+            gn0 = float(np.dot(g0, g0))
+            for s in range(S):
+                times = np.concatenate([[0.0], T[rec - 1, s]])
+                vals = np.concatenate([[float(f0)], val[rec - 1, s]])
+                gns = np.concatenate([[gn0], gn[rec - 1, s]])
+                traces.append(Trace(times, vals, gns, iterations=K,
+                                    total_time=float(total[s]),
+                                    gradients_used=int(used[s]),
+                                    gradients_computed=int(comp[s]),
+                                    x_final=np.asarray(x)[s]))
+        else:
+            e = np.array([])
+            for s in range(S):
+                traces.append(Trace(e, e, e, iterations=K,
+                                    total_time=float(total[s]),
+                                    gradients_used=int(used[s]),
+                                    gradients_computed=int(comp[s])))
     return traces

@@ -19,6 +19,7 @@ from typing import Any, Dict, List, Mapping, Optional, Sequence, Union
 
 import numpy as np
 
+from repro import telemetry
 from repro.core.batch import (TraceBatch, _as_spec, _grid_points,
                               simulate_batch)
 from repro.core.strategies import Trace
@@ -130,7 +131,8 @@ def _checkpointed_batch(strategy, model, K, *, problem, gamma, seed_list,
             raise ValueError(
                 f"checkpoint dir {checkpoint_dir!r} holds a different "
                 "sweep (manifest mismatch); refusing to resume into it")
-    atomic_write_json(manifest_path, manifest)
+    with telemetry.span("repro.exp.report"):
+        atomic_write_json(manifest_path, manifest)
 
     def _point_path(g: int) -> str:
         return os.path.join(checkpoint_dir, f"point-{g:05d}.json")
@@ -145,12 +147,13 @@ def _checkpointed_batch(strategy, model, K, *, problem, gamma, seed_list,
                              tol_grad_sq=tol_grad_sq, backend=backend,
                              rng_scheme=rng_scheme, use_pallas=use_pallas,
                              x64=x64)
-        rec = {"version": 1, "point": pt, "backend": sub.backend,
-               "rng_scheme": sub.rng_scheme,
-               "routing": sub.routing[0] if sub.routing else None,
-               "traces": [t.as_dict() for t in sub.traces[0]]}
-        atomic_write_json(_point_path(g), sanitize_json(rec),
-                          default=_jsonable)
+        with telemetry.span("repro.exp.report"):
+            rec = {"version": 1, "point": pt, "backend": sub.backend,
+                   "rng_scheme": sub.rng_scheme,
+                   "routing": sub.routing[0] if sub.routing else None,
+                   "traces": [t.as_dict() for t in sub.traces[0]]}
+            atomic_write_json(_point_path(g), sanitize_json(rec),
+                              default=_jsonable)
 
     traces: List[List[Trace]] = []
     backends: List[str] = []
@@ -233,50 +236,53 @@ def run_experiment(strategy,
     mode, and sharded routing records carry wall-clock compile times —
     use a deterministic backend when asserting byte equality.)
     """
-    if isinstance(scenario, str):
-        model = make_scenario(scenario, n, **(scenario_kwargs or {}))
-        scen_name = scenario
-    else:
-        model = scenario
-        scen_name = getattr(model, "name", type(model).__name__)
-    if model.n != n:
-        raise ValueError(f"scenario has n={model.n}, asked for n={n}")
+    with telemetry.span("repro.exp.run"):
+        if isinstance(scenario, str):
+            model = make_scenario(scenario, n, **(scenario_kwargs or {}))
+            scen_name = scenario
+        else:
+            model = scenario
+            scen_name = getattr(model, "name", type(model).__name__)
+        if model.n != n:
+            raise ValueError(f"scenario has n={model.n}, asked for n={n}")
 
-    if checkpoint_dir is not None:
-        seed_list = list(range(seeds)) \
-            if isinstance(seeds, (int, np.integer)) \
-            else [int(s) for s in seeds]
-        batch = _checkpointed_batch(
-            strategy, model, K, problem=problem, gamma=gamma,
-            seed_list=seed_list, grid=grid, record_every=record_every,
-            tol_grad_sq=tol_grad_sq, backend=backend,
-            rng_scheme=rng_scheme, use_pallas=use_pallas, x64=x64,
-            checkpoint_dir=checkpoint_dir, resume=resume)
-    else:
-        batch = simulate_batch(strategy, model, K, problem=problem,
-                               gamma=gamma, seeds=seeds, grid=grid,
-                               record_every=record_every,
-                               tol_grad_sq=tol_grad_sq, backend=backend,
-                               rng_scheme=rng_scheme, use_pallas=use_pallas,
-                               x64=x64)
-    rows = batch.summary(target_frac=target_frac)
-    for row in rows:
-        row["scenario"] = scen_name
-        row["n"] = n
-        row["K"] = K
-    meta = {"strategy": batch.strategy, "scenario": scen_name, "n": n,
-            "K": K, "seeds": list(map(int, batch.seeds)),
-            "backend": batch.backend,
-            "rng_scheme": batch.rng_scheme,
-            "routing": batch.routing,
-            "grid": batch.grid if grid else None}
-    result = ExperimentResult(name=name or f"{batch.strategy}@{scen_name}",
-                              meta=meta, batch=batch, rows=rows)
-    if json_path:
-        from repro.launch.sweep import is_coordinator
-        if is_coordinator():
-            result.to_json(json_path)
-    return result
+        if checkpoint_dir is not None:
+            seed_list = list(range(seeds)) \
+                if isinstance(seeds, (int, np.integer)) \
+                else [int(s) for s in seeds]
+            batch = _checkpointed_batch(
+                strategy, model, K, problem=problem, gamma=gamma,
+                seed_list=seed_list, grid=grid, record_every=record_every,
+                tol_grad_sq=tol_grad_sq, backend=backend,
+                rng_scheme=rng_scheme, use_pallas=use_pallas, x64=x64,
+                checkpoint_dir=checkpoint_dir, resume=resume)
+        else:
+            batch = simulate_batch(strategy, model, K, problem=problem,
+                                   gamma=gamma, seeds=seeds, grid=grid,
+                                   record_every=record_every,
+                                   tol_grad_sq=tol_grad_sq,
+                                   backend=backend, rng_scheme=rng_scheme,
+                                   use_pallas=use_pallas, x64=x64)
+        with telemetry.span("repro.exp.report"):
+            rows = batch.summary(target_frac=target_frac)
+            for row in rows:
+                row["scenario"] = scen_name
+                row["n"] = n
+                row["K"] = K
+            meta = {"strategy": batch.strategy, "scenario": scen_name,
+                    "n": n, "K": K, "seeds": list(map(int, batch.seeds)),
+                    "backend": batch.backend,
+                    "rng_scheme": batch.rng_scheme,
+                    "routing": batch.routing,
+                    "grid": batch.grid if grid else None}
+            result = ExperimentResult(
+                name=name or f"{batch.strategy}@{scen_name}", meta=meta,
+                batch=batch, rows=rows)
+            if json_path:
+                from repro.launch.sweep import is_coordinator
+                if is_coordinator():
+                    result.to_json(json_path)
+        return result
 
 
 def csv_rows(result: ExperimentResult, prefix: str,

@@ -224,7 +224,9 @@ def smallest_k(x, k: int, *, prefer_host: bool = None):
         prefer_host = jax.default_backend() == "cpu"
     if prefer_host and not isinstance(x, jax.core.Tracer):
         import numpy as np
-        xh = np.asarray(x)
+
+        from .. import telemetry
+        xh = np.asarray(telemetry.fetch(x))
         order = np.argsort(xh, axis=-1, kind="stable")
         if k < n:
             order = order[..., :k]

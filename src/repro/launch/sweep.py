@@ -58,6 +58,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .. import telemetry
+
 __all__ = ["SweepPoint", "sweep_device_count", "is_coordinator",
            "sweep_mesh", "sweep_shard_ctx", "shardable_kind",
            "run_sharded_sweep"]
@@ -183,6 +185,14 @@ def run_sharded_sweep(points: Sequence[SweepPoint], model, problem,
         kind = shardable_kind(p.strategy, model, problem)
         buckets.setdefault(_bucket_key(kind, p, math), []).append(p)
 
+    def host(comp, T, x, val, gn, *rest):
+        """A bucket's outputs on the host in one fetch; the iterate and
+        the recorded values only on the math path."""
+        if math:
+            return telemetry.fetch((comp, T, x, val, gn, *rest))
+        comp, T, *rest = telemetry.fetch((comp, T, *rest))
+        return (comp, T, None, None, None, *rest)
+
     def _run_bucket(bkey, bpoints
                     ) -> Dict[int, Tuple[List[Any], Dict[str, Any]]]:
         out: Dict[int, Tuple[List[Any], Dict[str, Any]]] = {}
@@ -205,9 +215,7 @@ def run_sharded_sweep(points: Sequence[SweepPoint], model, problem,
             comp, x, T, val, gn = bj.sharded_msync_run(
                 model, problem, n, len(unit_seeds), K, unit_seeds,
                 m_units, g_units, use_pallas, mesh, meta=meta)
-            comp, T = np.asarray(comp), np.asarray(T)
-            if math:
-                x, val, gn = np.asarray(x), np.asarray(val), np.asarray(gn)
+            comp, T, x, val, gn = host(comp, T, x, val, gn)
             for i, p in enumerate(bpoints):
                 c = slice(i * S, (i + 1) * S)
                 traces = bj.assemble_traces(
@@ -223,14 +231,13 @@ def run_sharded_sweep(points: Sequence[SweepPoint], model, problem,
                 model, problem, kind in ("ringmaster", "optimal_asgd"),
                 md, adaptive, n, len(unit_seeds), K, gamma, unit_seeds,
                 mesh=mesh, meta=meta)
-            comp, T = np.asarray(comp), np.asarray(T)
+            comp, T, x, val, gn = host(comp, T, x, val, gn)
             for i, p in enumerate(bpoints):
                 c = slice(i * S, (i + 1) * S)
                 traces = bj.assemble_traces(
-                    comp[c], None if not math else np.asarray(x)[c],
-                    T[:, c],
-                    None if not math else np.asarray(val)[:, c],
-                    None if not math else np.asarray(gn)[:, c],
+                    comp[c], None if not math else x[c], T[:, c],
+                    None if not math else val[:, c],
+                    None if not math else gn[:, c],
                     K, S, K, p.record_every, problem)
                 out[p.index] = (traces, {**base_rec, "padded_units": pad,
                                          **meta})
@@ -247,21 +254,18 @@ def run_sharded_sweep(points: Sequence[SweepPoint], model, problem,
                 comp, x, T, val, gn, used = bj._malenia_run(
                     model, problem, S_t, n, len(unit_seeds), K, gamma,
                     unit_seeds, mesh=mesh, meta=meta)
-                used = np.asarray(used)
             else:                                   # ringleader
                 _, K, gamma = bkey
                 comp, x, T, val, gn, used = bj._ringleader_run(
                     model, problem, n, len(unit_seeds), K, gamma,
                     unit_seeds, mesh=mesh, meta=meta)
-                used = np.asarray(used)
-            comp, T = np.asarray(comp), np.asarray(T)
+            comp, T, x, val, gn, used = host(comp, T, x, val, gn, used)
             for i, p in enumerate(bpoints):
                 c = slice(i * S, (i + 1) * S)
                 traces = bj.assemble_traces(
-                    comp[c], None if not math else np.asarray(x)[c],
-                    T[:, c],
-                    None if not math else np.asarray(val)[:, c],
-                    None if not math else np.asarray(gn)[:, c],
+                    comp[c], None if not math else x[c], T[:, c],
+                    None if not math else val[:, c],
+                    None if not math else gn[:, c],
                     used[c], S, K, p.record_every, problem)
                 out[p.index] = (traces, {**base_rec, "padded_units": pad,
                                          **meta})

@@ -67,13 +67,16 @@ class Model:
         return ctx.res(x)
 
     def _logits(self, params, x, ctx):
+        """Final norm and the (tied or separate) output projection, under
+        the ``head`` name scope."""
         cfg = self.cfg
-        x = norm_apply(params["final_norm"], x, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = x @ params["embed"]["embed"].T
-        else:
-            logits = x @ params["embed"]["unembed"]
-        return ctx.constrain(logits, ctx.dp, None, ctx.tp)
+        with jax.named_scope("head"):
+            x = norm_apply(params["final_norm"], x, cfg.norm_eps)
+            if cfg.tie_embeddings:
+                logits = x @ params["embed"]["embed"].T
+            else:
+                logits = x @ params["embed"]["unembed"]
+            return ctx.constrain(logits, ctx.dp, None, ctx.tp)
 
     def _encode(self, params, frames, ctx, impl="ref"):
         """Audio encoder: frames (B, F, D) stub embeddings -> memory."""
@@ -129,17 +132,19 @@ class Model:
             extra_embeds=batch.get("patch_embeds"),
             frames=batch.get("frames"), remat=remat, impl=impl)
         labels = batch["labels"]                        # (B, S)
-        lg = logits.astype(jnp.float32)
-        lse = jax.nn.logsumexp(lg, axis=-1)             # (B, S)
-        gold = jnp.take_along_axis(lg, labels[..., None], axis=-1)[..., 0]
-        nll = lse - gold
-        w = batch.get("loss_mask")
-        w = jnp.ones_like(nll) if w is None else w.astype(jnp.float32)
-        if example_weights is not None:
-            w = w * example_weights[:, None].astype(jnp.float32)
-        denom = jnp.maximum(w.sum(), 1.0)
-        ce = (nll * w).sum() / denom
-        zloss = 1e-4 * ((lse ** 2) * w).sum() / denom
+        with jax.named_scope("head"):                   # CE and z-loss
+            lg = logits.astype(jnp.float32)
+            lse = jax.nn.logsumexp(lg, axis=-1)         # (B, S)
+            gold = jnp.take_along_axis(lg, labels[..., None],
+                                       axis=-1)[..., 0]
+            nll = lse - gold
+            w = batch.get("loss_mask")
+            w = jnp.ones_like(nll) if w is None else w.astype(jnp.float32)
+            if example_weights is not None:
+                w = w * example_weights[:, None].astype(jnp.float32)
+            denom = jnp.maximum(w.sum(), 1.0)
+            ce = (nll * w).sum() / denom
+            zloss = 1e-4 * ((lse ** 2) * w).sum() / denom
         total = ce + zloss + aux
         return total, {"ce": ce, "z_loss": zloss, "aux_loss": aux}
 

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import telemetry
 from ..core.strategies import (AggregationStrategy, make_strategy)
 from ..core.sync_engine import (SimulatedStraggler, SyncPolicy, SyncMode,
                                 participation_example_weights)
@@ -56,7 +57,6 @@ class TrainHistory:
     losses: list = dataclasses.field(default_factory=list)
     m_used: list = dataclasses.field(default_factory=list)
     wall_seconds: list = dataclasses.field(default_factory=list)
-    step_times: list = dataclasses.field(default_factory=list)
 
 
 class Trainer:
@@ -164,34 +164,37 @@ class Trainer:
         split over the mesh's data axes when ``ctx`` has a mesh — and
         the round's participant count and simulated duration."""
         B = batch["tokens"].shape[0]
-        if self.straggler is not None:
-            mask, m, dur = self.straggler.step()
-            weights = participation_example_weights(
-                jnp.asarray(mask), self.n_workers, B)
-        else:
-            m, dur = self.n_workers, 0.0
-            weights = None
-        if self.ctx.mesh is not None:
-            split = self.ctx.sharding(self.ctx.dp)
-            batch = jax.device_put(dict(batch), split)
-            if weights is not None:
-                weights = jax.device_put(weights, split)
-        else:
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-        if self.grad_delay:
-            self._param_fifo.append(state.params)
-            grad_params = self._param_fifo[0]
-            if len(self._param_fifo) > self.grad_delay:
-                self._param_fifo.popleft()
-        else:
-            grad_params = None
-        args = (state.params, state.opt_state, batch, weights,
-                jnp.asarray(state.step, jnp.int32), grad_params)
+        with telemetry.span("repro.train.participation"):
+            if self.straggler is not None:
+                mask, m, dur = self.straggler.step()
+                weights = participation_example_weights(
+                    jnp.asarray(mask), self.n_workers, B)
+            else:
+                m, dur = self.n_workers, 0.0
+                weights = None
+        with telemetry.span("repro.train.put"):
+            if self.ctx.mesh is not None:
+                split = self.ctx.sharding(self.ctx.dp)
+                batch = jax.device_put(dict(batch), split)
+                if weights is not None:
+                    weights = jax.device_put(weights, split)
+            else:
+                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+            if self.grad_delay:
+                self._param_fifo.append(state.params)
+                grad_params = self._param_fifo[0]
+                if len(self._param_fifo) > self.grad_delay:
+                    self._param_fifo.popleft()
+            else:
+                grad_params = None
+            args = (state.params, state.opt_state, batch, weights,
+                    jnp.asarray(state.step, jnp.int32), grad_params)
         return args, m, dur
 
     def step(self, state: TrainState, batch: Dict[str, Any]):
         args, m, dur = self.step_inputs(state, batch)
-        params, opt_state, metrics = self.step_program(*args)
+        with telemetry.span("repro.train.dispatch"):
+            params, opt_state, metrics = self.step_program(*args)
         return (TrainState(params, opt_state, state.step + 1),
                 metrics, m, dur)
 
@@ -203,21 +206,22 @@ class Trainer:
         sim_t = hist.sim_seconds[-1] if hist.sim_seconds else 0.0
         wall0 = time.perf_counter()
         for i in range(num_steps):
-            t0 = time.perf_counter()
-            batch = next(batches)
-            state, metrics, m, dur = self.step(state, batch)
-            step_wall = time.perf_counter() - t0
-            sim_t += dur
-            if self.straggler is not None:
-                # feed measured variance proxy into AUTO_M's estimator
-                self.straggler.estimator.update_sigma2(
-                    float(metrics["grad_sq"]))
-            if state.step % log_every == 0 or i == num_steps - 1:
-                hist.steps.append(state.step)
-                hist.sim_seconds.append(sim_t)
-                hist.losses.append(float(metrics["loss"]))
-                hist.m_used.append(m)
-                hist.wall_seconds.append(time.perf_counter() - wall0)
-                hist.step_times.append(step_wall)
+            with telemetry.step_span("repro.train.step", state.step):
+                with telemetry.span("repro.train.feed"):
+                    batch = next(batches)
+                state, metrics, m, dur = self.step(state, batch)
+                sim_t += dur
+                if self.straggler is not None:
+                    # feed measured variance proxy into AUTO_M's estimator
+                    with telemetry.sync("repro.train.sync"):
+                        self.straggler.estimator.update_sigma2(
+                            float(metrics["grad_sq"]))
+                if state.step % log_every == 0 or i == num_steps - 1:
+                    with telemetry.sync("repro.train.log"):
+                        hist.steps.append(state.step)
+                        hist.sim_seconds.append(sim_t)
+                        hist.losses.append(float(metrics["loss"]))
+                        hist.m_used.append(m)
+                        hist.wall_seconds.append(time.perf_counter() - wall0)
         self.final_state = state
         return hist

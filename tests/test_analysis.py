@@ -231,6 +231,51 @@ def test_jit003_time_in_traced_closure(tmp_path):
     assert _rules(run_purity_pass(bad, x64_strict=False)) == ["JIT003"]
 
 
+def test_jit006_span_and_counter_in_traced_code(tmp_path):
+    # a host span or counter inside a scan step or a jitted closure fires
+    # once at trace time: each spelling is a finding
+    bad = _mod(tmp_path, """
+        import jax
+        from jax import lax
+        from repro import telemetry
+
+        def outer(xs, c0):
+            def step(c, x):
+                with telemetry.span("repro.engine.dispatch"):
+                    c = c + x
+                telemetry.count("host_syncs")
+                return c, c
+            return lax.scan(step, c0, xs)
+
+        @jax.jit
+        def f(x):
+            with jax.profiler.TraceAnnotation("repro.x"):
+                return x + 1
+        """)
+    assert _rules(run_purity_pass(bad, x64_strict=False)) \
+        == ["JIT006", "JIT006", "JIT006"]
+
+
+def test_jit006_host_spans_and_named_scopes_allowed(tmp_path):
+    # spans around the call of a jitted program are host code; inside it
+    # the device side is named with jax.named_scope
+    good = _mod(tmp_path, """
+        import jax
+        from .. import telemetry
+
+        @jax.jit
+        def f(x):
+            with jax.named_scope("head"):
+                return x + 1
+
+        def run(x):
+            with telemetry.span("repro.engine.dispatch"):
+                y = f(x)
+            return telemetry.wait(y)
+        """)
+    assert run_purity_pass(good, x64_strict=False) == []
+
+
 def test_jit004_attribute_mutation(tmp_path):
     bad = _mod(tmp_path, """
         import jax
