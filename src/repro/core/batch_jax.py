@@ -89,6 +89,7 @@ from __future__ import annotations
 
 import dataclasses
 import math as _math
+import types
 from typing import Callable, List, Optional, Sequence
 
 import numpy as np
@@ -445,24 +446,66 @@ def _grad_mean_fn(problem, B):
     return grad_mean
 
 
-def _general_run(model, problem, m, n, S, K, gamma, use_pallas, seeds):
-    """RNG-threading m-sync scan: random/universal time models and/or a
-    JaxProblem oracle.
+def _value_key(v, seen=()):
+    """A hashable key equal for equal values, or ``None`` where ``v`` is
+    not keyed by value: Python scalars, ``str``, ``None``, NumPy arrays
+    (dtype, shape, bytes), tuples of these, and plain functions by their
+    code, defaults and closure cells (recursively)."""
+    if v is None or type(v) in (bool, int, str):
+        return (type(v), v)
+    if type(v) is float:
+        return (float, v.hex())             # by bits: -0.0 apart from 0.0
+    if isinstance(v, (np.ndarray, np.generic)):
+        a = np.asarray(v)
+        if a.dtype.hasobject:
+            return None
+        return ("array", a.dtype.str, a.shape, a.tobytes())
+    if type(v) is tuple:
+        parts = tuple(_value_key(x, seen) for x in v)
+        return None if None in parts else ("tuple", parts)
+    if type(v) is types.FunctionType and id(v) not in seen:
+        seen = seen + (id(v),)
+        try:
+            cells = tuple(c.cell_contents for c in v.__closure__ or ())
+        except ValueError:                  # an empty cell
+            return None
+        kw = tuple(sorted((v.__kwdefaults__ or {}).items()))
+        parts = tuple(_value_key(x, seen)
+                      for x in (v.__defaults__ or ()) + kw + cells)
+        if None in parts:
+            return None
+        return ("fn", v.__module__, v.__qualname__, v.__code__, parts)
+    return None
 
-    Every seed's draw stream is a pure function of its ``PRNGKey(seed)``
-    (a 4-way split of its own carried key per round). Closes over the
-    sampler/oracle, so jit caching is per call.
-    """
+
+def _law_key(model):
+    """The part of an m-sync program's key that stands for the time law.
+
+    A sampled law is keyed by the value of its ``jax_sampler`` (its code
+    and what it closes over), so two ``exponential_times(1.0, n)``
+    objects share one program; FixedTimes by its taus. Anything the key
+    cannot read by value — a universal model's bound method, a closure
+    over a device array or an object — keys by identity."""
+    if isinstance(model, FixedTimes):
+        return ("fixed", _value_key(np.asarray(model.taus)))
+    if isinstance(model, SubExponentialTimes):
+        key = _value_key(model.jax_sampler)
+        if key is not None:
+            return ("sampled", key)
+    return _ById(model)
+
+
+def _msync_scan_prog(model, problem, m, n, S, K, gamma, use_pallas):
+    """The jitted m-sync round scan ``run(keys0, x_init) -> (comp, x, T,
+    val, gn)`` for one law, problem and shape."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     math = problem is not None
-    with telemetry.span(ENGINE_PREP):
-        keys0, x_init = _keys_and_x(problem, S, n, seeds)
-        finish_all = _finish_factory(model, S, n)
-        if math:
-            grad_mean = _grad_mean_fn(problem, m)
+    finish_all = _finish_factory(model, S, n)
+    if math:
+        grad_mean = _grad_mean_fn(problem, m)
 
     def step(carry, k):
         ft, ver, comp, x, keys = carry
@@ -484,8 +527,7 @@ def _general_run(model, problem, m, n, S, K, gamma, use_pallas, seeds):
             val = gn = jnp.zeros(S)
         return (ft, ver, comp, x, keys), (T, val, gn)
 
-    @jax.jit
-    def run(keys):
+    def run(keys, x_init):
         sub = jax.vmap(lambda kk: jax.random.split(kk, 2))(keys)
         ft0 = finish_all(sub[:, 1], jnp.zeros((S, n)))
         init = (ft0, jnp.zeros((S, n), jnp.int32), jnp.zeros(S, jnp.int32),
@@ -494,8 +536,47 @@ def _general_run(model, problem, m, n, S, K, gamma, use_pallas, seeds):
             step, init, jnp.arange(K, dtype=jnp.int32))
         return comp, x, T, val, gn
 
-    with telemetry.span(ENGINE_DISPATCH):
-        out = run(keys0)
+    return jax.jit(run)
+
+
+def _general_run(model, problem, m, n, S, K, gamma, use_pallas, seeds):
+    """RNG-threading m-sync scan: random/universal time models and/or a
+    JaxProblem oracle.
+
+    Every seed's draw stream is a pure function of its ``PRNGKey(seed)``
+    (a 4-way split of its own carried key per round). The program takes
+    the keys and the initial iterate as arguments and is built once per
+    key in :data:`_SWEEP_PROGS`: the shape and parameters, the x64 and
+    PRNG settings, the law by value (:func:`_law_key`), the problem by
+    identity and the module functions it is traced from. Later calls
+    with an equal key reuse it without tracing (counters
+    ``sweep_prog_builds`` / ``sweep_prog_hits``).
+    """
+    import jax
+
+    math = problem is not None
+    with telemetry.span(ENGINE_PREP):
+        keys0, x_init = _keys_and_x(problem, S, n, seeds)
+        # the module functions the trace reads are keyed too, so a
+        # rebound one (a patched round) builds anew
+        key = ("msync_scan", m, n, S, K, float(gamma), bool(use_pallas),
+               math, bool(jax.config.jax_enable_x64),
+               str(jax.config.jax_default_prng_impl), _law_key(model),
+               _ById(problem) if math else None,
+               (_finish_factory, _grad_mean_fn, _timing_round))
+        prog = _SWEEP_PROGS.get(key)
+        hit = prog is not None
+        if hit:
+            telemetry.count("sweep_prog_hits")
+        else:
+            telemetry.count("sweep_prog_builds")
+            prog = _prog_cache_put(
+                _SWEEP_PROGS, key,
+                _msync_scan_prog(model, problem, m, n, S, K, gamma,
+                                 use_pallas))
+
+    with telemetry.span(ENGINE_DISPATCH, cache_hit=hit):
+        out = prog(keys0, x_init)
     return telemetry.wait(out)
 
 
@@ -542,8 +623,11 @@ class _ById:
         return isinstance(other, _ById) and other.obj is self.obj
 
 
-#: AOT-compiled sharded sweep programs, FIFO like _CHAIN_PROGS/_SCAN_PROGS:
-#: key = (family, static shape/params, mesh devices, model/problem ids).
+#: Sweep programs, FIFO like _CHAIN_PROGS/_SCAN_PROGS: the AOT-compiled
+#: sharded ones, key = (family, static shape/params, mesh devices,
+#: model/problem ids), and the unsharded m-sync round scans of
+#: :func:`_general_run`, key = ("msync_scan", shape/params, settings,
+#: law key, problem id).
 _SWEEP_PROGS: dict = {}
 
 
@@ -2011,9 +2095,12 @@ def simulate_batch_jax(strategy: AggregationStrategy,
 
     The FixedTimes timing-only m-sync case and the timing-only
     arrival-scan programs hit module-level jit caches (no recompile
-    across calls of the same shape); the other programs close over the
-    oracle and sampler, so they recompile per call — fine for
-    sweep-sized S × K, not for tight loops of tiny calls.
+    across calls of the same shape). The other unsharded m-sync round
+    scans are cached per shape, parameters and law, the law keyed by
+    value (see :func:`_general_run`), so a fresh model object of the
+    same law reuses the program; the other families key their cached
+    programs by model and oracle identity, so a new object recompiles
+    — fine for sweep-sized S × K, not for tight loops of tiny calls.
     """
     import jax
     import jax.numpy as jnp

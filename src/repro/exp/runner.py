@@ -15,6 +15,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import threading
 from typing import Any, Dict, List, Mapping, Optional, Sequence, Union
 
 import numpy as np
@@ -36,9 +37,10 @@ def atomic_write_json(path: str, obj: Any, *, indent: int = 2,
     crash mid-write never leaves a truncated artifact: readers see
     either the previous complete file or the new complete file. The
     tmp file lives next to the target (same filesystem — ``os.replace``
-    is atomic only within one)."""
+    is atomic only within one) and is named for the writing process and
+    thread, so concurrent writers of one artifact never share it."""
     path = os.fspath(path)
-    tmp = f"{path}.tmp"
+    tmp = f"{path}.{os.getpid()}-{threading.get_ident()}.tmp"
     with open(tmp, "w") as fh:
         json.dump(obj, fh, indent=indent, default=default)
         fh.flush()

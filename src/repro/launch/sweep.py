@@ -4,8 +4,9 @@ work units of a :func:`repro.core.simulate_batch` sweep across devices.
 The paper's claims are statements about whole (scenario × strategy ×
 seed) grids, but every jax engine in :mod:`repro.core.batch_jax` vmaps
 seeds on a single device, so paper-scale sweeps serialize over grid
-points — and the closure-compiled programs (sampled models, oracles)
-recompile per point. This module is the ``backend="jax_sharded"``
+points — and each point runs a program of its own (the unsharded m-sync
+round scan is cached per law and shape; the other families' programs
+key by model and oracle identity). This module is the ``backend="jax_sharded"``
 orchestrator that fixes both:
 
 * **Flatten** — every (grid point, seed) pair becomes one *work unit*;
