@@ -1,4 +1,4 @@
 from .base import (AttnConfig, Block, EncoderConfig, InputShape,
-                   INPUT_SHAPES, ModelConfig, MoEConfig, SSMConfig, Stage,
-                   reduced)
+                   INPUT_SHAPES, MLAConfig, ModelConfig, MoEConfig,
+                   SSMConfig, Stage, reduced)
 from .registry import ALIASES, ARCH_IDS, all_configs, get_config

@@ -19,8 +19,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
-__all__ = ["AttnConfig", "MoEConfig", "SSMConfig", "Block", "Stage",
-           "ModelConfig", "InputShape", "INPUT_SHAPES", "reduced"]
+__all__ = ["AttnConfig", "MLAConfig", "MoEConfig", "SSMConfig", "Block",
+           "Stage", "ModelConfig", "InputShape", "INPUT_SHAPES", "reduced"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,6 +31,25 @@ class AttnConfig:
     rope_theta: Optional[float] = 10000.0   # None => learned/none (whisper)
     causal: bool = True
     sliding_window: Optional[int] = None    # tokens; None => full attention
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values from a
+    normed latent of ``kv_lora_rank``, a rope part of ``qk_rope_head_dim``
+    that all heads share, YaRN rope (``models/mla.py``). Head count from
+    ``ModelConfig.attn.num_heads``."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float = 10000.0
+    rope_factor: float = 1.0           # YaRN scaling factor (1 = plain rope)
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -46,6 +65,19 @@ class MoEConfig:
     # axes (FSDP-style storage, gathered per layer). Required when total
     # expert params exceed model-axis-only capacity (kimi-k2 1T).
     shard_experts_2d: bool = False
+    # top-k weights renormalised to sum to 1 (False: the softmax scores)
+    norm_topk_prob: bool = True
+    # the chip's share of an expert-parallel layer: it holds experts
+    # [held_offset, held_offset + experts_held) of num_experts, routes over
+    # all of them and computes its own experts' part, dropless. 0 = the
+    # layer holds every expert (capacity path above).
+    experts_held: int = 0
+    held_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.experts_held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -93,6 +125,7 @@ class ModelConfig:
     stages: Tuple[Stage, ...]
     attn: AttnConfig
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None     # for "mla" mixers
     ssm: Optional[SSMConfig] = None
     encoder: Optional[EncoderConfig] = None   # audio enc-dec
     vision_tokens: int = 0          # VLM stub: patch embeddings prepended
@@ -102,6 +135,8 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     dtype: str = "bfloat16"         # activation / param dtype
+    embed_scale: bool = False       # token rows times sqrt(d_model)
+    remat: bool = False             # recompute each layer in the backward
     max_seq_len: int = 8192
     citation: str = ""
 
@@ -153,7 +188,14 @@ class ModelConfig:
 def _block_params(cfg: ModelConfig, b: Block, active: bool = False) -> int:
     d = cfg.d_model
     n = 0
-    if b.mixer in ("attn", "cross"):
+    if b.mixer == "mla":
+        a, h = cfg.mla, cfg.attn.num_heads
+        qk = a.qk_nope_head_dim + a.qk_rope_head_dim
+        n += d * h * qk                                     # q
+        n += d * (a.kv_lora_rank + a.qk_rope_head_dim)      # latent, k_pe
+        n += a.kv_lora_rank * h * (a.qk_nope_head_dim + a.v_head_dim)
+        n += h * a.v_head_dim * d + a.kv_lora_rank          # o, latent norm
+    elif b.mixer in ("attn", "cross"):
         a = cfg.attn
         qkv = d * a.num_heads * a.head_dim + 2 * d * a.num_kv_heads * a.head_dim
         o = a.num_heads * a.head_dim * d
@@ -178,8 +220,10 @@ def _block_params(cfg: ModelConfig, b: Block, active: bool = False) -> int:
         m = cfg.moe
         mult = 3 if cfg.mlp_act == "swiglu" else 2
         per_expert = mult * d * m.d_expert
-        routed = (m.experts_per_token if active else m.num_experts)
-        n += routed * per_expert
+        # held experts; per token, the routed ones that are held here
+        routed = (m.experts_per_token * m.held / m.num_experts if active
+                  else m.held)
+        n += int(routed * per_expert)
         n += m.num_shared_experts * mult * d * m.d_shared
         n += d * m.num_experts      # router
     n += 2 * d                      # norms
@@ -220,9 +264,15 @@ def reduced(cfg: ModelConfig, d_model: int = 128, layers_per_stage: int = 1,
             experts_per_token=min(m.experts_per_token, 2),
             d_expert=d_model, d_shared=d_model if m.num_shared_experts else 0,
             num_shared_experts=min(m.num_shared_experts, 1),
+            experts_held=min(m.experts_held, max_experts // 2),
             # no token drops in the reduced variant so decode == forward
             # exactly (the full configs keep the production 1.25 factor)
             capacity_factor=float(2 * max_experts))
+    mla = cfg.mla
+    if mla is not None:
+        mla = dataclasses.replace(mla, kv_lora_rank=d_model // 2,
+                                  qk_nope_head_dim=16, qk_rope_head_dim=8,
+                                  v_head_dim=16)
     ssm = cfg.ssm
     if ssm is not None:
         ssm = dataclasses.replace(ssm, d_state=8, head_dim=32)
@@ -251,6 +301,6 @@ def reduced(cfg: ModelConfig, d_model: int = 128, layers_per_stage: int = 1,
             frontend_len=16)
     return dataclasses.replace(
         cfg, name=cfg.name + "-reduced", d_model=d_model, vocab_size=vocab,
-        d_ff=2 * d_model, stages=stages, attn=attn, moe=moe, ssm=ssm,
+        d_ff=2 * d_model, stages=stages, attn=attn, moe=moe, mla=mla, ssm=ssm,
         encoder=enc, vision_tokens=min(cfg.vision_tokens, 4),
         dtype="float32", max_seq_len=512)

@@ -17,6 +17,7 @@ CONFIG = ModelConfig(
     mlp_act="gelu",
     norm="layernorm",
     tie_embeddings=True,
+    embed_scale=True,
     max_seq_len=512,
     citation="github.com/karpathy/nanoGPT (paper §J)",
 )

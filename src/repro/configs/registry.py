@@ -21,6 +21,7 @@ ARCH_IDS = [
     "kimi_k2_1t_a32b",
     "mistral_nemo_12b",
     "deepseek_moe_16b",
+    "deepseek_v2_lite",
     # paper's own experiment model (Section J / K.5)
     "nanogpt_paper",
 ]
@@ -37,6 +38,7 @@ ALIASES = {
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
     "mistral-nemo-12b": "mistral_nemo_12b",
     "deepseek-moe-16b": "deepseek_moe_16b",
+    "deepseek-v2-lite-ep8": "deepseek_v2_lite",
     "nanogpt-paper": "nanogpt_paper",
 }
 

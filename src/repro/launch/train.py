@@ -56,9 +56,12 @@ def build_run(arch: str = "nanogpt-paper", *, steps: int = 100,
     """The launcher's run, built but not started:
     ``(cfg, trainer, data)``. :func:`main` maps its flags onto these
     arguments; ``ctx`` (not a flag) puts the trainer on a
-    data-parallel mesh."""
+    data-parallel mesh. The configuration runs as the registry states
+    it (a cut it states included); ``reduced=True`` asks for the
+    CPU-scale variant at ``d_model``. Layers are recomputed in the
+    backward pass if ``remat`` or the configuration asks for it."""
     cfg = get_config(arch)
-    if reduced or cfg.param_count() > 1e9:
+    if reduced:
         cfg = reduce_cfg(cfg, d_model=d_model, layers_per_stage=2,
                          vocab=min(cfg.vocab_size, 2048))
     model = build_model(cfg)
@@ -74,7 +77,8 @@ def build_run(arch: str = "nanogpt-paper", *, steps: int = 100,
         raise SystemExit("--policy requires a --time-model")
 
     trainer = Trainer(model, opt, n_workers=workers, sync_policy=sync,
-                      time_model=tm, ctx=ctx, remat=remat, seed=seed)
+                      time_model=tm, ctx=ctx, remat=remat or cfg.remat,
+                      seed=seed)
     data = SyntheticLM(vocab_size=cfg.vocab_size, seq_len=seq,
                        batch_size=batch, seed=seed)
     return cfg, trainer, data

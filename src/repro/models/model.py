@@ -13,8 +13,8 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..sharding.specs import ShardCtx
 from .layers import init_embedding, init_norm, norm_apply
-from .transformer import (init_stage, init_stage_cache, stage_apply,
-                          stage_decode)
+from .transformer import (init_stage, init_stage_cache, no_stats,
+                          stage_apply, stage_decode)
 
 __all__ = ["Model", "build_model"]
 
@@ -59,7 +59,8 @@ class Model:
         # float32 — in bf16 the updates to frequent tokens' rows are
         # rounded away as the row's sum grows
         x = table.astype(jnp.float32)[tokens].astype(table.dtype)
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)  # (B, S, D)
+        if cfg.embed_scale:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)  # (B, S, D)
         if cfg.pos_embed == "learned":
             S = tokens.shape[1]
             pos = params["embed"]["pos_embed"][offset:offset + S]
@@ -100,6 +101,14 @@ class Model:
         ``extra_embeds``: (B, N, D) VLM patch embeddings, prepended.
         ``frames``: (B, F, D) audio-stub embeddings for enc-dec models.
         """
+        logits, stats = self._forward(params, tokens, ctx,
+                                      extra_embeds=extra_embeds,
+                                      frames=frames, remat=remat, impl=impl)
+        return logits, stats["aux_loss"]
+
+    def _forward(self, params, tokens, ctx, *, extra_embeds, frames, remat,
+                 impl):
+        """Forward pass -> (logits, stats summed over every block)."""
         cfg = self.cfg
         ctx = ctx or ShardCtx.null()
         x = self._embed(params, tokens, ctx)
@@ -112,25 +121,28 @@ class Model:
         if cfg.encoder is not None:
             assert frames is not None, "enc-dec model needs frames"
             memory = self._encode(params, frames, ctx, impl=impl)
-        aux_total = jnp.zeros((), jnp.float32)
+        total = no_stats()
         for sp, s in zip(params["stages"], cfg.stages):
-            x, aux = stage_apply(sp, x, s, ctx, cfg, memory=memory,
-                                 remat=remat, impl=impl)
-            aux_total = aux_total + aux
+            x, stats = stage_apply(sp, x, s, ctx, cfg, memory=memory,
+                                   remat=remat, impl=impl)
+            total = jax.tree.map(jnp.add, total, stats)
         if n_prefix:
             x = x[:, n_prefix:]
-        return self._logits(params, x, ctx), aux_total
+        return self._logits(params, x, ctx), total
 
     def loss(self, params, batch: dict, ctx: Optional[ShardCtx] = None, *,
              remat: bool = False, impl: str = "ref",
              example_weights=None) -> Tuple[jnp.ndarray, dict]:
         """Next-token CE (+ MoE aux + z-loss). ``example_weights`` (B,)
-        realizes the m-sync participation mask (core/sync_engine)."""
+        realizes the m-sync participation mask (core/sync_engine). With
+        experts, the metrics also hold ``moe_held_rows``: the assignments
+        the held experts computed, summed over layers."""
         ctx = ctx or ShardCtx.null()
-        logits, aux = self.apply(
+        logits, stats = self._forward(
             params, batch["tokens"], ctx,
             extra_embeds=batch.get("patch_embeds"),
             frames=batch.get("frames"), remat=remat, impl=impl)
+        aux = stats["aux_loss"]
         labels = batch["labels"]                        # (B, S)
         with jax.named_scope("head"):                   # CE and z-loss
             lg = logits.astype(jnp.float32)
@@ -146,7 +158,10 @@ class Model:
             ce = (nll * w).sum() / denom
             zloss = 1e-4 * ((lse ** 2) * w).sum() / denom
         total = ce + zloss + aux
-        return total, {"ce": ce, "z_loss": zloss, "aux_loss": aux}
+        metrics = {"ce": ce, "z_loss": zloss, "aux_loss": aux}
+        if self.cfg.moe is not None:
+            metrics["moe_held_rows"] = stats["moe_held_rows"]
+        return total, metrics
 
     # ---------------- serving ----------------
     def init_cache(self, batch: int, max_len: int) -> dict:

@@ -21,7 +21,8 @@ from ..configs.base import Block, ModelConfig, Stage
 from .attention import (attn_apply, decode_attn_apply, init_attn,
                         init_kv_cache)
 from .layers import init_mlp, init_norm, mlp_apply, norm_apply
-from .moe import init_moe, moe_apply
+from .mla import init_mla, mla_apply
+from .moe import init_moe, moe_apply, moe_forward
 from .ssm import (init_mamba, init_mamba_state, init_rwkv, init_rwkv_state,
                   mamba_apply, mamba_decode, rwkv_apply, rwkv_decode)
 
@@ -42,6 +43,8 @@ def init_block(key, cfg: ModelConfig, block: Block) -> dict:
     if block.mixer in ("attn", "cross"):
         p["mixer"] = init_attn(ks[0], d, a.num_heads, a.num_kv_heads,
                                a.head_dim, dtype=dt)
+    elif block.mixer == "mla":
+        p["mixer"] = init_mla(ks[0], d, a.num_heads, cfg.mla, dtype=dt)
     elif block.mixer == "mamba":
         p["mixer"] = init_mamba(ks[0], d, cfg.ssm, dtype=dt)
     elif block.mixer == "rwkv":
@@ -68,14 +71,24 @@ def init_stage(key, cfg: ModelConfig, stage: Stage) -> dict:
     return jax.vmap(init_unit)(keys)
 
 
+def no_stats() -> dict:
+    """What a block adds to the forward pass's sums besides its output:
+    the weighted MoE aux loss and the assignments its held experts
+    computed."""
+    return {"aux_loss": jnp.zeros((), jnp.float32),
+            "moe_held_rows": jnp.zeros((), jnp.int32)}
+
+
 def _block_apply(p: dict, x, block: Block, ctx, cfg, *, memory=None,
                  impl: str = "ref"):
-    """One block forward (train/prefill). Returns (x, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+    """One block forward (train/prefill). Returns (x, stats)."""
+    stats = no_stats()
     h = norm_apply(p["norm1"], x, cfg.norm_eps)
     if block.mixer == "attn":
         h = attn_apply(p["mixer"], h, ctx, cfg, causal=cfg.attn.causal,
                        impl=impl)
+    elif block.mixer == "mla":
+        h = mla_apply(p["mixer"], h, cfg)
     elif block.mixer == "cross":
         h = attn_apply(p["mixer"], h, ctx, cfg, kv_x=memory, causal=False,
                        impl=impl)
@@ -90,35 +103,39 @@ def _block_apply(p: dict, x, block: Block, ctx, cfg, *, memory=None,
         if block.ff == "mlp":
             h = mlp_apply(p["ff"], h, cfg.mlp_act, ctx)
         else:
-            h, aux = moe_apply(p["ff"], h, ctx, cfg)
+            h, stats = moe_forward(p["ff"], h, ctx, cfg)
         x = x + h
         x = ctx.res(x)
-    return x, aux
+    return x, stats
+
+
+def _add(a: dict, b: dict) -> dict:
+    return jax.tree.map(jnp.add, a, b)
 
 
 def stage_apply(params: dict, x, stage: Stage, ctx, cfg, *, memory=None,
                 remat: bool = False, impl: str = "ref"):
-    """Forward through a stage. Returns (x, aux_loss_sum)."""
+    """Forward through a stage. Returns (x, stats summed over its blocks;
+    see :func:`no_stats`)."""
 
     def unit(x, unit_params):
-        aux_total = jnp.zeros((), jnp.float32)
+        total = no_stats()
         for i, b in enumerate(stage.pattern):
-            x, aux = _block_apply(unit_params[f"b{i}"], x, b, ctx, cfg,
-                                  memory=memory, impl=impl)
-            aux_total = aux_total + aux
-        return x, aux_total
+            x, stats = _block_apply(unit_params[f"b{i}"], x, b, ctx, cfg,
+                                    memory=memory, impl=impl)
+            total = _add(total, stats)
+        return x, total
 
     if remat:
         unit = jax.checkpoint(unit)
 
     def body(carry, unit_params):
-        x, aux_sum = carry
-        x, aux = unit(x, unit_params)
-        return (x, aux_sum + aux), None
+        x, total = carry
+        x, stats = unit(x, unit_params)
+        return (x, _add(total, stats)), None
 
-    (x, aux_sum), _ = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                   params)
-    return x, aux_sum
+    (x, total), _ = jax.lax.scan(body, (x, no_stats()), params)
+    return x, total
 
 
 # ---------------------------------------------------------------------------

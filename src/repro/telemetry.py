@@ -14,10 +14,14 @@ Spans belong to host code only: inside a traced function they would
 fire once, at trace time (repcheck rule JIT006). Device code is named
 with ``jax.named_scope`` instead.
 
-``host_syncs`` is the one counter: every call that blocks the host on
-device results goes through :func:`wait` (``block_until_ready``),
+``host_syncs`` counts every call that blocks the host on device
+results: each goes through :func:`wait` (``block_until_ready``),
 :func:`fetch` (a host copy) or, where the caller times it, a :func:`sync`
-span; each opens a span and counts the call once.
+span; each opens a span and counts the call once. ``moe_held_rows``
+counts the expert assignments that held experts computed, summed over
+layers and steps: ``Trainer.run`` reads a step's count in the same host
+read as the step's ``grad_sq`` (steps with a straggler model), adding no
+sync.
 
 The span names are a contract with the benchmark that reads them
 (``PERF.md`` lists which metric reads each):
@@ -29,6 +33,11 @@ The span names are a contract with the benchmark that reads them
 * trainer: ``repro.train.step`` (a step annotation), ``repro.train.feed``,
   ``repro.train.participation``, ``repro.train.put``,
   ``repro.train.dispatch``, ``repro.train.sync``, ``repro.train.log``.
+
+Device code names its parts with ``jax.named_scope``: ``head`` (final
+norm, output projection, loss), ``mla`` (latent attention),
+``moe_route``, ``moe_experts`` and ``moe_combine`` (a layer of held
+experts).
 """
 
 from __future__ import annotations
@@ -39,11 +48,13 @@ from typing import Any, Dict
 import jax
 
 __all__ = ["Span", "span", "step_span", "count", "counters", "sync",
-           "wait", "fetch", "HOST_SYNCS", "ENGINE_PREP", "ENGINE_DISPATCH",
-           "ENGINE_WAIT"]
+           "wait", "fetch", "HOST_SYNCS", "MOE_HELD_ROWS", "ENGINE_PREP",
+           "ENGINE_DISPATCH", "ENGINE_WAIT"]
 
 #: the counter of calls that block the host on device results
 HOST_SYNCS = "host_syncs"
+#: the counter of expert assignments computed by held experts
+MOE_HELD_ROWS = "moe_held_rows"
 
 ENGINE_PREP = "repro.engine.prep"
 ENGINE_DISPATCH = "repro.engine.dispatch"

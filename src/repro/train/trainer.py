@@ -212,10 +212,15 @@ class Trainer:
                 state, metrics, m, dur = self.step(state, batch)
                 sim_t += dur
                 if self.straggler is not None:
-                    # feed measured variance proxy into AUTO_M's estimator
+                    # feed measured variance proxy into AUTO_M's estimator;
+                    # the step's held-expert rows come back in the same read
                     with telemetry.sync("repro.train.sync"):
-                        self.straggler.estimator.update_sigma2(
-                            float(metrics["grad_sq"]))
+                        gsq, rows = jax.device_get(
+                            (metrics["grad_sq"],
+                             metrics.get(telemetry.MOE_HELD_ROWS)))
+                        self.straggler.estimator.update_sigma2(float(gsq))
+                    if rows is not None:
+                        telemetry.count(telemetry.MOE_HELD_ROWS, int(rows))
                 if state.step % log_every == 0 or i == num_steps - 1:
                     with telemetry.sync("repro.train.log"):
                         hist.steps.append(state.step)

@@ -1,5 +1,6 @@
-"""Compile the main path's kernels and the NanoGPT step for one TPU v5e
-chip that is described, not attached.
+"""Compile the main path's kernels, the NanoGPT step and DeepSeek-V2-Lite's
+latent attention and held-expert layer for one TPU v5e chip that is
+described, not attached.
 
 The TPU compiler is installed with jaxlib's TPU support; it refuses what
 interpret mode accepts (loop carries Mosaic cannot lower, blocks over
@@ -96,3 +97,43 @@ def test_nanogpt_step_fits_one_chip(one_chip):
         None).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < TEMP_LIMIT, mem
+
+
+def test_held_expert_layer_compiles_at_deepseek_widths(one_chip):
+    """A DeepSeek-V2-Lite layer of 8 held experts (d 2048, width 1408,
+    top 6 of 64) and its gradient compile for one chip over a row of
+    4096 tokens; the grouped products lower to Mosaic kernels."""
+    from repro.configs import get_config
+    from repro.models.moe import init_moe, moe_forward
+    from repro.sharding.specs import ShardCtx
+
+    cfg = get_config("deepseek-v2-lite-ep8")
+    p = jax.eval_shape(lambda k: init_moe(k, 2048, cfg.moe, "swiglu",
+                                          jnp.bfloat16), jax.random.key(0))
+    p = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), p)
+    x = _spec((1, 4096, 2048), jnp.bfloat16, one_chip)
+
+    def loss(p, x):
+        out, stats = moe_forward(p, x, ShardCtx.null(), cfg)
+        return out.astype(jnp.float32).sum() + stats["aux_loss"]
+
+    compiled = jax.jit(jax.grad(loss)).lower(p, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_latent_attention_compiles_at_deepseek_widths(one_chip):
+    """MLA at DeepSeek-V2-Lite's widths (16 heads, latent 512, rope 64)
+    and its gradient compile for one chip over 1024 positions, in query
+    blocks."""
+    from repro.configs import get_config
+    from repro.models.mla import init_mla, mla_apply
+
+    cfg = get_config("deepseek-v2-lite-ep8")
+    p = jax.eval_shape(lambda k: init_mla(k, 2048, 16, cfg.mla,
+                                          jnp.bfloat16), jax.random.key(0))
+    p = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), p)
+    x = _spec((2, 1024, 2048), jnp.bfloat16, one_chip)
+    compiled = jax.jit(jax.grad(
+        lambda p, x: mla_apply(p, x, cfg).astype(jnp.float32).sum())
+    ).lower(p, x).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < TEMP_LIMIT
